@@ -45,9 +45,10 @@ smoke-sweep:
 
 # Executor-machine sweep smoke: the real-JAX lane executor driven through
 # SweepSpec/run_sweep (tiny grid, spawn-pool fan-out, measured cells).
+# Fan-out needs JAX held to the CPU: a chip belongs to one process.
 smoke-sweep-executor:
-	$(PY) -m benchmarks.run --machine executor --jobs 2 --subset 1 \
-	    --no-cache
+	JAX_PLATFORMS=cpu $(PY) -m benchmarks.run --machine executor --jobs 2 \
+	    --subset 1 --no-cache
 
 # Closed-loop sweep smoke: completion-driven M/G/k + think-time cells
 # (arrival processes fed by the DES feedback edge) through the same

@@ -8,10 +8,11 @@ batch) is a grid of ``num_blocks`` homogeneous *blocks* (steps); a job's
 one block at a time, so the executor is the paper's machine with SMs=lanes.
 
 Time model: lanes advance on a virtual clock ordered by *measured* wall
-time of each real step execution (this container has one physical device,
-so lane parallelism is virtual while every block's duration is a real
-measurement — including JIT, cache and memory effects).  On a real pod the
-same loop runs with concurrent lanes and wall-clock time.
+time of each real step execution.  Lanes are virtual: every block runs in
+the driver thread on the process's one device (a CPU, or one TPU chip), so
+lane parallelism is modelled while every block's duration is a real
+measurement — including JIT, cache and memory effects.  On one chip, one
+lane is the honest machine.
 
 The executor is the second concrete :class:`repro.core.machine.Machine`
 (the DES simulator is the first): the same

@@ -5,14 +5,22 @@ A training job's block is one fixed-size microbatch optimizer step; a
 serving job's block is one k-token decode chunk for a request batch.  Both
 are homogeneous, which is exactly the structural property the paper's
 predictor exploits.
+
+Serve jobs of one configuration share what a server holds once: one weight
+set per (configuration, weight seed) and one compiled prefill/decode pair
+per (configuration, batch, prompt length, cache length).  Each job owns
+only its prompt, its KV cache and the tokens it generates.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional
+import functools
+from dataclasses import dataclass, field
+from typing import Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs.base import ArchConfig
@@ -94,6 +102,45 @@ def make_train_job(
                        warmup_fn=warmup, tenant=tenant)
 
 
+@functools.lru_cache(maxsize=None)
+def serve_params(cfg: ArchConfig, weight_seed: int) -> Dict:
+    """The weight set that every serve job of ``cfg`` shares.
+
+    Cached for the life of the process: a server holds one copy of each
+    model it serves, however many jobs (and KV caches) it runs on it.
+    """
+    return jax.jit(functools.partial(lm.init, cfg))(
+        jax.random.PRNGKey(weight_seed))
+
+
+# Module-level jits keyed by the static configuration and cache length:
+# every job of one (configuration, batch, prompt length, cache length)
+# reuses one compiled program instead of tracing its own closure.
+@functools.partial(jax.jit, static_argnums=0, static_argnames="max_seq")
+def _prefill(cfg: ArchConfig, params, tokens, *, max_seq: int):
+    return lm.prefill(cfg, params, tokens, max_seq=max_seq)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _decode(cfg: ArchConfig, params, token, caches, lengths):
+    logits, caches = lm.decode_step(cfg, params, token, caches, lengths)
+    return jnp.argmax(logits, -1), caches
+
+
+@dataclass
+class ServeJob(ExecutorJob):
+    """A serving job; its blocks append each generated ``[batch]`` token
+    array, still on the device, to ``generated``."""
+
+    params: Optional[Dict] = None
+    generated: List = field(default_factory=list)
+
+    def tokens(self) -> np.ndarray:
+        """``[batch, n_generated]`` token ids, fetched from the device in
+        one transfer."""
+        return np.asarray(jnp.stack(self.generated, axis=1))
+
+
 def make_serve_job(
     cfg: ArchConfig,
     name: str,
@@ -105,50 +152,50 @@ def make_serve_job(
     max_residency: int = 4,
     arrival: float = 0.0,
     seed: int = 0,
+    weight_seed: int = 0,
     tenant: Optional[str] = None,
-) -> ExecutorJob:
+) -> ServeJob:
     """A serving job: ``blocks`` decode chunks of ``tokens_per_block`` each
-    against a live KV cache (prefill happens in the first block)."""
-    key = jax.random.PRNGKey(seed)
+    against a live KV cache (prefill happens in the first block).
+
+    ``seed`` draws the prompt; ``weight_seed`` picks the shared weight set
+    (:func:`serve_params`).
+    """
+    params = serve_params(cfg, weight_seed)
     max_seq = prompt_len + blocks * tokens_per_block + 8
-    state: Dict = {"params": lm.init(cfg, key), "caches": None,
-                   "lengths": None, "token": None}
-
-    @jax.jit
-    def do_prefill(params, tokens):
-        return lm.prefill(cfg, params, tokens, max_seq=max_seq)
-
-    @jax.jit
-    def do_decode(params, token, caches, lengths):
-        logits, caches = lm.decode_step(cfg, params, token, caches, lengths)
-        return jnp.argmax(logits, -1), caches
-
-    prompt = jax.random.randint(key, (batch, prompt_len), 0, cfg.vocab_size)
+    prompt = jax.random.randint(jax.random.PRNGKey(seed),
+                                (batch, prompt_len), 0, cfg.vocab_size)
+    state: Dict = {"caches": None, "lengths": None, "token": None}
+    generated: List = []
 
     def warmup():
-        logits, caches = do_prefill(state["params"], prompt)
+        logits, caches = _prefill(cfg, params, prompt, max_seq=max_seq)
         tok = jnp.argmax(logits, -1)
         lengths = jnp.full((batch,), prompt_len, jnp.int32)
-        out = do_decode(state["params"], tok, caches, lengths)
+        out = _decode(cfg, params, tok, caches, lengths)
         jax.block_until_ready(out[0])   # compile only; discard results
 
     def make_block_fn(residency: int) -> Callable[[], None]:
         def block():
             if state["caches"] is None:
-                logits, caches = do_prefill(state["params"], prompt)
+                logits, caches = _prefill(cfg, params, prompt,
+                                          max_seq=max_seq)
                 state["caches"] = caches
                 state["lengths"] = jnp.full((batch,), prompt_len, jnp.int32)
                 state["token"] = jnp.argmax(logits, -1)
+                generated.append(state["token"])
             for _ in range(tokens_per_block):
-                tok, caches = do_decode(state["params"], state["token"],
-                                        state["caches"], state["lengths"])
+                tok, caches = _decode(cfg, params, state["token"],
+                                      state["caches"], state["lengths"])
                 state["token"] = tok
                 state["caches"] = caches
                 state["lengths"] = state["lengths"] + 1
+                generated.append(tok)
             jax.block_until_ready(state["token"])
         return block
 
-    return ExecutorJob(name=name, num_blocks=blocks,
-                       max_residency=max_residency,
-                       make_block_fn=make_block_fn, arrival=arrival,
-                       warmup_fn=warmup, tenant=tenant)
+    return ServeJob(name=name, num_blocks=blocks,
+                    max_residency=max_residency,
+                    make_block_fn=make_block_fn, arrival=arrival,
+                    warmup_fn=warmup, tenant=tenant, params=params,
+                    generated=generated)

@@ -28,7 +28,10 @@ executes it:
   durations are wall-clock measurements;
 * **fan-out**: with ``jobs > 1`` cells run in a process pool (fork for the
   pure-Python DES; spawn for executor cells, because forking a process
-  with an initialized JAX runtime can deadlock).  Executor solo baselines
+  with an initialized JAX runtime can deadlock).  Executor fan-out is
+  refused unless ``JAX_PLATFORMS=cpu``: an accelerator belongs to one
+  process, so there executor cells run in the process that holds it.
+  Executor solo baselines
   are measured under the *same* pool-contention conditions as the cells:
   with ``jobs > 1`` they go through an identical spawn pool of the same
   width (serial parent-process baselines would be systematically faster
@@ -81,6 +84,7 @@ import dataclasses
 import hashlib
 import math
 import multiprocessing
+import os
 import time
 import uuid
 from concurrent.futures import ProcessPoolExecutor
@@ -661,6 +665,20 @@ def _materialize(spec: SweepSpec) -> Tuple[List[tuple], Dict[tuple, KernelSpec]]
     return worklist, solo_specs
 
 
+def _require_cpu_for_executor_pool(jobs: int) -> None:
+    """Refuse executor fan-out off the CPU.
+
+    Spawned workers each initialise JAX on the default backend, and a chip
+    belongs to one process: the second worker would fail or hang.  Reads
+    the environment only, so the parent initialises no backend to decide.
+    """
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise ValueError(
+            f"executor sweeps with jobs={jobs} need JAX_PLATFORMS=cpu: "
+            "executor cells run in the process that holds the chip, so "
+            "run them with jobs=1 there")
+
+
 def _measure_solos(solo_specs: Dict[tuple, KernelSpec], spec: SweepSpec,
                    jobs: int, cache_dir: Optional[Path]
                    ) -> Tuple[Dict[tuple, float], Dict[str, int]]:
@@ -730,6 +748,7 @@ def _measure_solos(solo_specs: Dict[tuple, KernelSpec], spec: SweepSpec,
         payloads = [{"spec": solo_specs[mk], "n_lanes": spec.n_sm,
                      "time_scale": spec.time_scale} for mk in misses]
         if pool_jobs > 1:
+            _require_cpu_for_executor_pool(pool_jobs)
             ctx = multiprocessing.get_context("spawn")
             with ProcessPoolExecutor(max_workers=pool_jobs,
                                      mp_context=ctx) as pool:
@@ -879,6 +898,7 @@ def _execute_pending(pending: List[dict], jobs: int,
                 # an initialized JAX runtime can deadlock — spawn workers
                 # instead (they re-import and re-JIT, which the per-cell
                 # compile cost dominates anyway).
+                _require_cpu_for_executor_pool(jobs)
                 batch.sort(key=_cost, reverse=True)
                 ctx = multiprocessing.get_context("spawn")
                 with ProcessPoolExecutor(max_workers=jobs,

@@ -8,7 +8,8 @@ MXU-aligned (block_q x D and block_k x D tiles, D a multiple of 128 for the
 zoo's head dims).
 
 Validated against the pure-jnp oracle in interpret mode on CPU
-(tests/test_kernels.py); TPU is the compilation target.
+(tests/test_kernels.py, ``interpret=True``) and compiled for v5e at yi-6b
+widths (tests/test_chip_compile.py); TPU is the compilation target.
 """
 
 from __future__ import annotations
@@ -19,6 +20,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -78,7 +80,7 @@ def flash_attention_pallas(
     scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     B, Sq, H, D = q.shape
     Sk, KV = k.shape[1], k.shape[2]
@@ -121,19 +123,11 @@ def flash_attention_pallas(
                                lambda b, h, i, j: (b, h, 0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, qr.shape[3], Dv), q.dtype),
         scratch_shapes=[
-            pl.ScratchShape((G, block_q), jnp.float32)
-            if hasattr(pl, "ScratchShape") else _vmem((G, block_q)),
-            pl.ScratchShape((G, block_q), jnp.float32)
-            if hasattr(pl, "ScratchShape") else _vmem((G, block_q)),
-            pl.ScratchShape((G, block_q, Dv), jnp.float32)
-            if hasattr(pl, "ScratchShape") else _vmem((G, block_q, Dv)),
+            pltpu.VMEM((G, block_q), jnp.float32),
+            pltpu.VMEM((G, block_q), jnp.float32),
+            pltpu.VMEM((G, block_q, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(qr, kr, vr)
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, qr.shape[3], H, Dv)
     return out[:, :Sq]
-
-
-def _vmem(shape):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, jnp.float32)

@@ -8,7 +8,8 @@ Each op has up to three interchangeable implementations:
   distributed train/serve paths (online-softmax KV-chunk streaming for
   attention, chunked SSD, associative-scan RG-LRU, sort-based MoE dispatch),
 * ``backend="pallas"`` — the Pallas TPU kernels (see flash_attention.py,
-  ssd_scan.py, ...), validated on CPU with ``interpret=True``.
+  ssd_scan.py, ...), compiled for the TPU; the tests validate them on CPU
+  with ``interpret=True``.
 
 All implementations are tested against the reference over shape/dtype
 sweeps in ``tests/test_kernels.py``.
@@ -245,10 +246,10 @@ def ssd(
     """Mamba-2 SSD (state-space duality) mixer: (y, final_state)."""
     if backend == "ref":
         return ref.ssd_scan(x, dt, A, Bmat, Cmat, initial_state)
-    if backend == "pallas":
+    if backend == "pallas" and initial_state is None:
         from .ssd_scan import ssd_pallas
-        return ssd_pallas(x, dt, A, Bmat, Cmat, chunk=chunk,
-                          initial_state=initial_state)
+        return ssd_pallas(x, dt, A, Bmat, Cmat, chunk=chunk)
+    # Resumed scans take the XLA path: the kernel starts from zero.
     return _ssd_chunked_xla(x, dt, A, Bmat, Cmat, chunk, initial_state)
 
 
@@ -349,10 +350,10 @@ def rglru(
     """RG-LRU linear recurrence: (h [B,S,C], final_state [B,C])."""
     if backend == "ref":
         return ref.rglru_scan(x, gate_a, gate_i, log_a, initial_state, c)
-    if backend == "pallas":
+    if backend == "pallas" and initial_state is None:
         from .rglru_scan import rglru_pallas
-        return rglru_pallas(x, gate_a, gate_i, log_a,
-                            initial_state=initial_state, c=c)
+        return rglru_pallas(x, gate_a, gate_i, log_a, c=c)
+    # Resumed scans take the XLA path: the kernel starts from zero.
     # Two-level scan: associative scan *within* chunks (parallel, O(log Q)
     # depth), lax.scan *across* chunks threading the [B, C] state.  The
     # chunk body is checkpointed so the backward pass recomputes one chunk

@@ -18,6 +18,7 @@ import math
 from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -30,7 +31,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)}; "
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before importing jax (dry-run) or run on a real pod")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_test_mesh(shape: Tuple[int, ...] = (2, 2),
@@ -40,7 +41,15 @@ def make_test_mesh(shape: Tuple[int, ...] = (2, 2),
     devices = jax.devices()
     if len(devices) < n:
         raise RuntimeError(f"need {n} devices, have {len(devices)}")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
+
+
+def _auto_mesh(shape, axes, devices):
+    # Auto axes: the model code places activations with
+    # with_sharding_constraint, which JAX refuses on Explicit axes (the
+    # make_mesh default since JAX 0.7).
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def batch_axes_of(mesh) -> Tuple[str, ...]:

@@ -49,6 +49,12 @@ its win.  Reported metrics are the steady-state queueing view
 (:func:`repro.core.metrics.evaluate_queueing` over machine-time
 arrivals/finishes) plus the usual STP/ANTT.
 
+Jobs are built from the ``reduced()`` CPU configuration unless
+``--layers N`` asks for the published widths with the depth cut to ``N``
+layers (the one-chip share that ``chip_smoke.py`` serves).  Jobs of one
+configuration share one weight set drawn from ``--seed``; each job's prompt
+has a seed of its own.
+
 Example::
 
     PYTHONPATH=src python -m repro.launch.serve \
@@ -68,13 +74,15 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import dataclasses
 import itertools
-from typing import Callable, Dict, List, Tuple
+from typing import Callable, Dict, List, NamedTuple, Tuple
 
 from repro.configs import get_arch
-from repro.core.executor import LaneExecutor
+from repro.configs.base import ArchConfig
+from repro.core.executor import ExecutorJob, JobResult, LaneExecutor
 from repro.core.jobs import make_serve_job
-from repro.core.metrics import evaluate, evaluate_queueing
+from repro.core.metrics import WorkloadMetrics, evaluate, evaluate_queueing
 from repro.core.policies import make_policy
 from repro.core.scenarios import (
     executor_job,
@@ -85,6 +93,7 @@ from repro.core.scenarios import (
 from repro.core.scheduler_service import SchedulerService
 from repro.core.sweep import solo_runtime_executor_cached
 from repro.core.workload import Arrival, scaled_spec
+from repro.launch.compile_cache import use_compile_cache
 
 
 def parse_jobs(args) -> List[Tuple[str, int]]:
@@ -95,12 +104,22 @@ def parse_jobs(args) -> List[Tuple[str, int]]:
     return out
 
 
+def serve_config(args, arch_id: str) -> ArchConfig:
+    """``--layers N``: published widths, ``N`` layers; else ``reduced()``."""
+    cfg = get_arch(arch_id)
+    if args.layers:
+        return dataclasses.replace(cfg, n_layers=args.layers)
+    return cfg.reduced()
+
+
 def build_job(args, arch_id: str, blocks: int, seed: int):
+    """One serve job; ``seed`` draws its prompt, and every job of the arch
+    shares the weight set of ``--seed``."""
     return make_serve_job(
-        get_arch(arch_id).reduced(), arch_id, blocks=blocks,
+        serve_config(args, arch_id), arch_id, blocks=blocks,
         tokens_per_block=args.tokens_per_block, batch=args.batch,
         prompt_len=args.prompt_len, max_residency=args.lanes,
-        seed=seed, tenant=arch_id)
+        seed=seed, weight_seed=args.seed, tenant=arch_id)
 
 
 def scenario_arrivals(args):
@@ -199,21 +218,34 @@ def submission_plan(args, solo: Dict[object, float]
     ]
 
 
-async def run_service(args, policy: str, solo: Dict[object, float]):
+class ServiceRun(NamedTuple):
+    """What one policy run produced: metrics, per-job results and the jobs
+    themselves (keyed like the results)."""
+
+    metrics: WorkloadMetrics
+    results: List[JobResult]
+    jobs: Dict[str, ExecutorJob]
+
+
+async def run_service(args, policy: str,
+                      solo: Dict[object, float]) -> ServiceRun:
     """One policy run: staggered async submissions against a live service."""
     service = SchedulerService(n_lanes=args.lanes, policy=policy,
                                predictor=args.predictor)
     plan = submission_plan(args, solo)
     try:
         handles = []
+        jobs: Dict[str, ExecutorJob] = {}
         solo_by_key: Dict[str, float] = {}
         t0 = asyncio.get_event_loop().time()
         for offset, job_factory, tenant, solo_rt in plan:
             delay = t0 + offset - asyncio.get_event_loop().time()
             if delay > 0:
                 await asyncio.sleep(delay)  # late arrival, busy machine
-            handle = service.submit(job_factory(), tenant=tenant,
+            job = job_factory()
+            handle = service.submit(job, tenant=tenant,
                                     solo_runtime=solo_rt)
+            jobs[handle.key] = job
             solo_by_key[handle.key] = solo_rt
             handles.append(handle)
         results = [await h.result() for h in handles]
@@ -227,7 +259,7 @@ async def run_service(args, policy: str, solo: Dict[object, float]):
     print_tenant_report(service)
     for r in sorted(results, key=lambda r: r.key):
         print(f"    {r.key}: turnaround={r.turnaround:.2f}s")
-    return m
+    return ServiceRun(m, results, jobs)
 
 
 def print_tenant_report(service: SchedulerService) -> None:
@@ -314,10 +346,10 @@ async def run_service_closed_loop(args, policy: str,
 
 
 def run_policy(args, policy: str, solo: Dict[Tuple[str, int], float]):
-    return asyncio.run(run_service(args, policy, solo))
+    return asyncio.run(run_service(args, policy, solo)).metrics
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--jobs", default="yi-6b:24,minicpm3-4b:6",
                     help="arch:decode_blocks,...")
@@ -364,10 +396,20 @@ def main() -> None:
     ap.add_argument("--max-blocks", type=int, default=16,
                     help="cap scenario grids at this many real blocks per "
                          "job (with --scenario-kernels; 0 = uncapped)")
-    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--layers", type=int, default=0,
+                    help="serve the published widths with the depth cut to "
+                         "this many layers (0 = the reduced CPU config)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="weight seed; job i draws its prompt from seed+i")
+    return ap
+
+
+def main() -> None:
+    ap = build_parser()
     args = ap.parse_args()
     if args.scenario_kernels and not args.scenario:
         ap.error("--scenario-kernels requires --scenario")
+    use_compile_cache()
     solo = measure_solo(args)
     if args.closed_loop > 0:
         q = asyncio.run(run_service_closed_loop(args, args.policy, solo))

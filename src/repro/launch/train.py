@@ -37,6 +37,7 @@ from repro.core.metrics import evaluate
 from repro.core.policies import make_policy
 from repro.core.predictor import staircase_runtime
 from repro.data import pipeline as data
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.steps import build_train_step
 from repro.models import lm
 from repro.optim import adamw
@@ -160,6 +161,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true",
                     help="use the full (pod-scale) config — real pods only")
     args = ap.parse_args()
+    use_compile_cache()
     if args.jobs:
         train_multi(args)
     else:

@@ -86,16 +86,15 @@ def _moe_shard_map(p: Dict, x, idx, gate, cfg: MoEConfig, shard, dtype):
         out = ops.moe_combine(y, meta, T)
         return out.reshape(B_l, S_l, D)
 
-    from jax.experimental.shard_map import shard_map
     act_spec = P(b_ax, s_ax, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(act_spec, act_spec, act_spec,
                   P(model_axis, w_data, None),
                   P(model_axis, w_data, None),
                   P(model_axis, None, w_data)),
         out_specs=act_spec,
-        check_rep=False)
+        check_vma=False)
     return fn(x, idx, gate, p["gate_w"], p["up_w"], p["down_w"])
 
 

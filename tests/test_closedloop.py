@@ -391,9 +391,11 @@ def test_executor_solo_key_folds_in_pool_width():
 
 
 @pytest.mark.slow
-def test_executor_parallel_sweep_measures_solos_in_the_pool(tmp_path):
+def test_executor_parallel_sweep_measures_solos_in_the_pool(tmp_path,
+                                                          monkeypatch):
     from repro.core.scenarios import TraceReplay
 
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")   # spawned workers inherit it
     scn = TraceReplay(trace=[{"kernel": "SAD", "time": 0.0},
                              {"kernel": "JPEG-d", "time": 100.0}],
                       specs=TINYX, name="xtiny")

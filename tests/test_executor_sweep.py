@@ -95,11 +95,25 @@ def test_executor_truncation_first_class():
 
 
 @pytest.mark.slow
-def test_executor_parallel_fanout_produces_all_cells(tmp_path):
+def test_executor_parallel_fanout_produces_all_cells(tmp_path, monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")   # spawned workers inherit it
     result = run_sweep(exec_spec(("fifo", "srtf", "mpmax")), jobs=2,
                        cache_dir=tmp_path)
     assert result.stats["computed"] == 3
     assert all(c.metrics is not None for c in result.cells)
+
+
+@pytest.mark.parametrize("platforms", [None, "tpu", "cpu,tpu"])
+def test_executor_fanout_refused_off_the_cpu(tmp_path, monkeypatch,
+                                             platforms):
+    """One chip belongs to one process: executor cells are never handed to
+    spawned workers unless JAX is held to the CPU."""
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    with pytest.raises(ValueError, match="JAX_PLATFORMS=cpu"):
+        run_sweep(exec_spec(("fifo",)), jobs=2, cache_dir=tmp_path)
 
 
 # ----------------------------------------------------- quarantine regression

@@ -53,7 +53,7 @@ def test_attention_matches_oracle(case, impl):
                                   kv_chunk=7)
     else:
         got = flash_attention_pallas(q, k, v, mask_kind=kind, window=window,
-                                     block_q=8, block_k=8)
+                                     block_q=8, block_k=8, interpret=True)
     tol = TOL if dtype == jnp.bfloat16 else TOL32
     np.testing.assert_allclose(np.asarray(got, np.float32), want,
                                rtol=tol["rtol"] * 10, atol=tol["atol"] * 10)
@@ -99,7 +99,8 @@ def test_decode_attention_matches_oracle(case, impl):
     if impl == "xla":
         got = ops.decode_attention(q, kc, vc, length)
     else:
-        got = decode_attention_pallas(q, kc, vc, length, block_k=8)
+        got = decode_attention_pallas(q, kc, vc, length, block_k=8,
+                                      interpret=True)
     np.testing.assert_allclose(np.asarray(got, np.float32), want,
                                rtol=2e-2 if dtype == jnp.bfloat16 else 1e-4,
                                atol=2e-2 if dtype == jnp.bfloat16 else 1e-4)
@@ -129,7 +130,7 @@ def test_ssd_matches_oracle(case, impl):
     if impl == "xla":
         y, h = ops.ssd(x, dt, A, Bm, Cm, chunk=chunk)
     else:
-        y, h = ssd_pallas(x, dt, A, Bm, Cm, chunk=chunk)
+        y, h = ssd_pallas(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
     tol = 3e-2 if dtype == jnp.bfloat16 else 1e-4
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(y_ref, np.float32),
@@ -150,6 +151,40 @@ def test_ssd_with_initial_state():
     y_ref, h_ref = ref.ssd_scan(x, dt, A, Bm, Cm, h0)
     y, h = ops.ssd(x, dt, A, Bm, Cm, chunk=8, initial_state=h0)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_pallas_scans_refuse_initial_state_and_ops_resumes_them():
+    """The kernels start from a zero state: a direct call with a state
+    raises, and ``ops`` hands a resumed scan to the XLA path explicitly."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 6)
+    B, S, H, P, G, N = 1, 16, 2, 4, 1, 8
+    x = jax.random.normal(ks[0], (B, S, H, P)) * 0.5
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
+    A = -jnp.exp(jax.random.normal(ks[2], (H,)))
+    Bm = jax.random.normal(ks[3], (B, S, G, N)) * 0.3
+    Cm = jax.random.normal(ks[4], (B, S, G, N)) * 0.3
+    h0 = jax.random.normal(ks[5], (B, H, P, N)) * 0.2
+    with pytest.raises(ValueError, match="zero state"):
+        ssd_pallas(x, dt, A, Bm, Cm, chunk=8, initial_state=h0,
+                   interpret=True)
+    y_ref, _ = ref.ssd_scan(x, dt, A, Bm, Cm, h0)
+    y, _ = ops.ssd(x, dt, A, Bm, Cm, chunk=8, initial_state=h0,
+                   backend="pallas")
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               rtol=1e-4, atol=1e-4)
+
+    C = 8
+    xr = jax.random.normal(ks[0], (B, S, C))
+    ga = jax.nn.sigmoid(jax.random.normal(ks[1], (B, S, C)))
+    gi = jax.nn.sigmoid(jax.random.normal(ks[2], (B, S, C)))
+    la = -jax.nn.softplus(jax.random.normal(ks[3], (C,))) * 0.1
+    s0 = jax.random.normal(ks[4], (B, C))
+    with pytest.raises(ValueError, match="zero state"):
+        rglru_pallas(xr, ga, gi, la, initial_state=s0, interpret=True)
+    h_ref, _ = ref.rglru_scan(xr, ga, gi, la, s0)
+    h, _ = ops.rglru(xr, ga, gi, la, initial_state=s0, backend="pallas")
+    np.testing.assert_allclose(np.asarray(h), np.asarray(h_ref),
                                rtol=1e-4, atol=1e-4)
 
 
@@ -196,7 +231,7 @@ def test_rglru_matches_oracle(case, impl):
     if impl == "xla":
         h, hT = ops.rglru(x, ga, gi, la)
     else:
-        h, hT = rglru_pallas(x, ga, gi, la, chunk=16)
+        h, hT = rglru_pallas(x, ga, gi, la, chunk=16, interpret=True)
     tol = 3e-2 if dtype == jnp.bfloat16 else 1e-4
     np.testing.assert_allclose(np.asarray(h, np.float32),
                                np.asarray(h_ref, np.float32),
