@@ -35,6 +35,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
+from . import tracing
 from .events import BlockEnded, BlockStarted, KernelArrived, KernelEnded, grants_issue
 from .machine import KernelRun, MachineBase
 from .predictor import Predictor
@@ -70,11 +71,13 @@ class ExecutorJob:
 
 
 class _LaneState:
-    __slots__ = ("index", "busy", "resident", "failed", "slow_factor")
+    __slots__ = ("index", "busy", "last", "resident", "failed",
+                 "slow_factor")
 
     def __init__(self, index: int):
         self.index = index
         self.busy: Optional[str] = None       # job key currently running
+        self.last: Optional[str] = None       # job key of the last block
         self.resident: Dict[int, str] = {}
         self.failed = False
         self.slow_factor = 1.0
@@ -192,7 +195,8 @@ class LaneExecutor(MachineBase):
         self.runs[key] = KernelRun(key, job.grid_spec(), arrival, order)
         self._invalidate_active()
         if warmup and job.warmup_fn is not None:
-            job.warmup_fn()
+            with tracing.span("executor.warmup", job=key):
+                job.warmup_fn()
         heapq.heappush(self._events,
                        (arrival, 0, next(self._seq), ("arrival", key)))
         return key
@@ -225,7 +229,7 @@ class LaneExecutor(MachineBase):
             key, run.arrival_time, self.now, run.done,
             self.failures_absorbed, cancelled=True)
         if run.launched:
-            self.core.post(KernelEnded(key, self.now))
+            self._post(KernelEnded(key, self.now))
         self._dispatch()
         return True
 
@@ -320,7 +324,7 @@ class LaneExecutor(MachineBase):
     def _on_arrival(self, key: str) -> None:
         if self.runs[key].finished:
             return      # cancelled before its queued arrival event fired
-        self.core.post(KernelArrived(key, self.now))
+        self._post(KernelArrived(key, self.now))
 
     def _on_block_end(self, key: str, lane_idx: int, lost: bool,
                       bid: int = -1) -> None:
@@ -331,7 +335,7 @@ class LaneExecutor(MachineBase):
             # failed lane: block's work is discarded, re-issue it
             run.issued -= 1
             self.failures_absorbed += 1
-            self.core.post(BlockEnded(key, lane_idx, 0, self.now, lost=True))
+            self._post(BlockEnded(key, lane_idx, 0, self.now, lost=True))
             return
         if run.cancelled:
             # the job was cancelled while this block was in flight; the
@@ -343,13 +347,20 @@ class LaneExecutor(MachineBase):
             self.predictor.on_block_end(key, lane_idx, 0, self.now)
             return
         run.done += 1
-        self.core.post(BlockEnded(key, lane_idx, 0, self.now))
+        total = self._post(BlockEnded(key, lane_idx, 0, self.now))
+        if tracing.enabled():
+            if total is not None:
+                tracing.count("predictor.total_s", total, job=key,
+                              lane=lane_idx, done=run.done,
+                              blocks=run.spec.num_blocks)
+            tracing.sample_memory("hbm.sample",
+                                  jobs_held=len(self._block_fns))
         if run.done >= run.spec.num_blocks:
             run.finish_time = self.now
             self.results[key] = JobResult(
                 key, run.arrival_time, self.now, run.done,
                 self.failures_absorbed)
-            self.core.post(KernelEnded(key, self.now))
+            self._post(KernelEnded(key, self.now))
             # Natural completion only: cancel() posts KernelEnded too, but
             # a frontend cancellation is not the machine finishing work and
             # must not trigger closed-loop resubmission.
@@ -371,6 +382,21 @@ class LaneExecutor(MachineBase):
             self.predictor.reslice_all(key)
         self.sync_residency_caps()
 
+    # The scheduling core's calls, timed as ``executor.schedule`` while the
+    # tracer records; off, they cost one test (they run between blocks,
+    # while the device waits).
+    def _post(self, event):
+        if not tracing.enabled():
+            return self.core.post(event)
+        with tracing.span("executor.schedule", job=event.key):
+            return self.core.post(event)
+
+    def _decide(self, lane: int):
+        if not tracing.enabled():
+            return self.core.decide(lane)
+        with tracing.span("executor.schedule", lane=lane):
+            return self.core.decide(lane)
+
     def _dispatch(self) -> None:
         progressed = True
         while progressed:
@@ -378,7 +404,7 @@ class LaneExecutor(MachineBase):
             for lane in self.sms:
                 if lane.busy is not None or lane.failed:
                     continue
-                key = grants_issue(self.core.decide(lane.index))
+                key = grants_issue(self._decide(lane.index))
                 if key is None or not self.can_fit(key, lane.index):
                     continue
                 self._start_block(key, lane)
@@ -388,15 +414,26 @@ class LaneExecutor(MachineBase):
         run = self.runs[key]
         residency = self._residency(key) + 1
         fn = self._block_fn(key, residency)
+        traced = tracing.enabled()
+        if traced and lane.last not in (None, key):
+            last = self.runs[lane.last]
+            if not last.finished and last.unissued > 0:
+                tracing.count("scheduler.preemptions", 1, lane=lane.index,
+                              job=key, preempted=lane.last)
         # Baselined determinism finding (wallclock): real wall time IS this
         # machine's time model — executor cells are measurements, marked
         # measured=True and nonce-keyed out of cross-run cache hits.
         t0 = time.perf_counter()
         fn()                                        # REAL computation
-        dur = (time.perf_counter() - t0) * lane.slow_factor
+        t1 = time.perf_counter()
+        if traced:
+            tracing.interval("executor.block", t0, t1, job=key,
+                             block=run.issued, residency=residency)
+        dur = (t1 - t0) * lane.slow_factor
         lane.busy = key
+        lane.last = key
         run.issued += 1
-        self.core.post(BlockStarted(key, lane.index, 0, self.now))
+        self._post(BlockStarted(key, lane.index, 0, self.now))
         self.trace.append((key, lane.index, self.now, self.now + dur))
         # straggler mitigation: quarantine lanes whose EWMA step time
         # exceeds the cross-lane median by the threshold factor

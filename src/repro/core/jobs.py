@@ -27,6 +27,7 @@ from repro.configs.base import ArchConfig
 from repro.models import lm
 from repro.optim import adamw
 
+from . import tracing
 from .executor import ExecutorJob
 
 
@@ -178,20 +179,24 @@ def make_serve_job(
     def make_block_fn(residency: int) -> Callable[[], None]:
         def block():
             if state["caches"] is None:
-                logits, caches = _prefill(cfg, params, prompt,
-                                          max_seq=max_seq)
-                state["caches"] = caches
-                state["lengths"] = jnp.full((batch,), prompt_len, jnp.int32)
-                state["token"] = jnp.argmax(logits, -1)
-                generated.append(state["token"])
-            for _ in range(tokens_per_block):
-                tok, caches = _decode(cfg, params, state["token"],
-                                      state["caches"], state["lengths"])
-                state["token"] = tok
-                state["caches"] = caches
-                state["lengths"] = state["lengths"] + 1
-                generated.append(tok)
-            jax.block_until_ready(state["token"])
+                with tracing.span("job.prefill"):
+                    logits, caches = _prefill(cfg, params, prompt,
+                                              max_seq=max_seq)
+                    state["caches"] = caches
+                    state["lengths"] = jnp.full((batch,), prompt_len,
+                                                jnp.int32)
+                    state["token"] = jnp.argmax(logits, -1)
+                    generated.append(state["token"])
+            with tracing.span("job.dispatch"):
+                for _ in range(tokens_per_block):
+                    tok, caches = _decode(cfg, params, state["token"],
+                                          state["caches"], state["lengths"])
+                    state["token"] = tok
+                    state["caches"] = caches
+                    state["lengths"] = state["lengths"] + 1
+                    generated.append(tok)
+            with tracing.span("job.sync"):
+                jax.block_until_ready(state["token"])
         return block
 
     return ServeJob(name=name, num_blocks=blocks,

@@ -38,6 +38,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Union
 
+from . import tracing
 from .executor import ExecutorJob, JobResult, LaneExecutor
 from .metrics import WorkloadMetrics, evaluate
 from .policies import Policy, make_policy
@@ -101,7 +102,8 @@ class SchedulerService:
         self._ex = LaneExecutor([], policy, n_lanes=n_lanes,
                                 predictor=predictor)
         self._lock = threading.Condition()
-        self._pending: deque = deque()       # (job, key, tenant, solo)
+        # (job, key, tenant, solo, time.perf_counter() at submission)
+        self._pending: deque = deque()
         self._cancels: deque = deque()       # keys
         self._handles: Dict[str, JobHandle] = {}
         self._ledgers: Dict[str, _TenantLedger] = {}
@@ -130,7 +132,8 @@ class SchedulerService:
             self._count += 1
             handle = JobHandle(key, tenant, self)
             self._handles[key] = handle
-            self._pending.append((job, key, tenant, solo_runtime))
+            self._pending.append((job, key, tenant, solo_runtime,
+                                  time.perf_counter()))
             self._lock.notify()
         return handle
 
@@ -233,24 +236,36 @@ class SchedulerService:
         ex = self._ex
         tenants: Dict[str, str] = {}
         solo_hints: Dict[str, Optional[float]] = {}
+
+        def idle() -> bool:
+            return (not self._pending and not self._cancels
+                    and not ex.pending_events() and not self._closed)
+
         while True:
             with self._lock:
                 # Block until there is work: every producer (submit,
                 # _request_cancel, close) notifies under this lock, and the
                 # machine's event queue only changes from this thread, so an
                 # untimed wait cannot miss a wakeup.
-                while (not self._pending and not self._cancels
-                       and not ex.pending_events() and not self._closed):
-                    self._lock.wait()
+                if idle():
+                    with tracing.span("service.idle"):
+                        while idle():
+                            self._lock.wait()
                 if (self._closed and not self._pending and not self._cancels
                         and not ex.pending_events()):
                     break
                 pending, self._pending = list(self._pending), deque()
                 cancels, self._cancels = list(self._cancels), deque()
-            for job, key, tenant, solo in pending:
+            for job, key, tenant, solo, submitted in pending:
                 tenants[key] = tenant
                 solo_hints[key] = solo
-                ex.add_job(job, key=key)
+                # The hand-off ends when the driver turns to this job: after
+                # the block it was running and the warm-ups before it.
+                taken = time.perf_counter()
+                tracing.interval("service.handoff", submitted, taken, job=key)
+                with tracing.span("service.pickup", job=key,
+                                  waited_s=taken - submitted):
+                    ex.add_job(job, key=key)
             for key in cancels:
                 ex.cancel(key)
             ex.step()

@@ -393,16 +393,18 @@ _FINGERPRINT_SOURCES: Dict[str, Tuple[str, ...]] = {
     # would silently serve records produced by unvetted engine code.
     "des": ("distrib", "simulator", "machine", "events", "policies",
             "predictor", "workload", "metrics", "scenarios", "executor",
-            "fastsim", "fastsim_c", "fastsim_twin"),
+            "fastsim", "fastsim_c", "fastsim_twin", "tracing"),
     # Closed-loop DES cells also depend on scenarios.py directly: the
     # arrival *process* code (not a materialized list) determines what the
     # cell simulates, so an edit to it must invalidate those cells.
     "des-closed": ("distrib", "simulator", "machine", "events", "policies",
                    "predictor", "workload", "metrics", "scenarios",
-                   "executor", "fastsim", "fastsim_c", "fastsim_twin"),
+                   "executor", "fastsim", "fastsim_c", "fastsim_twin",
+                   "tracing"),
     "executor": ("distrib", "simulator", "machine", "events", "policies",
                  "predictor", "workload", "metrics", "scenarios",
-                 "executor", "fastsim", "fastsim_c", "fastsim_twin"),
+                 "executor", "fastsim", "fastsim_c", "fastsim_twin",
+                 "tracing"),
 }
 
 
