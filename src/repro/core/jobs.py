@@ -25,6 +25,7 @@ import numpy as np
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs.base import ArchConfig
 from repro.models import lm
+from repro.models.layers import DEFAULT_COMPUTE_DTYPE, cast
 from repro.optim import adamw
 
 from . import tracing
@@ -103,14 +104,49 @@ def make_train_job(
                        warmup_fn=warmup, tenant=tenant)
 
 
+# The leaves that a served step reads only through ``cast(leaf, compute
+# dtype)``: the matrix-product weights of linears, MLPs and attention, the
+# head and the embedding table.
+_COMPUTE_DTYPE_LEAVES = frozenset({"w", "wq", "wk", "wv", "wo", "table"})
+
+
+def _served_leaf(path, leaf: jnp.ndarray) -> jnp.ndarray:
+    if path[-1].key in _COMPUTE_DTYPE_LEAVES:
+        return cast(leaf, DEFAULT_COMPUTE_DTYPE)
+    return leaf
+
+
+def serve_weights(cfg: ArchConfig, key) -> Dict:
+    """The float32 set of :func:`lm.init`, with each matrix-product weight
+    (``w`` of linears and MLPs, ``wq``/``wk``/``wv``/``wo``, ``lm_head.w``)
+    and the embedding table held in the compute dtype (bfloat16).
+
+    The served steps read these leaves only through ``cast(leaf, compute
+    dtype)``, so rounding them once here feeds every matrix product the
+    same bits as rounding them on every step, and a step converts no
+    weight.  Every other leaf stays float32 as drawn: the norm scales and
+    the recurrent mixers' decay and step parameters, which the steps read
+    in float32, and the biases and the latent and recurrent mixers' own
+    projections, which they cast at use as before.  Training keeps its
+    float32 masters (:func:`make_train_job`).
+    """
+    return jax.tree_util.tree_map_with_path(_served_leaf, lm.init(cfg, key))
+
+
 @functools.lru_cache(maxsize=None)
 def serve_params(cfg: ArchConfig, weight_seed: int) -> Dict:
-    """The weight set that every serve job of ``cfg`` shares.
+    """The weight set that every serve job of ``cfg`` shares:
+    :func:`serve_weights` drawn from ``weight_seed``, its matrix weights
+    and embedding table in bfloat16, every other leaf in float32.  The
+    steps cast those leaves to bfloat16 at use anyway, so they compute the
+    same values as from the float32 set, without a cast on every step.
 
-    Cached for the life of the process: a server holds one copy of each
-    model it serves, however many jobs (and KV caches) it runs on it.
+    The draw and the cast are one program, so no whole float32 set is ever
+    held on the device.  Cached for the life of the process: a server holds
+    one copy of each model it serves, however many jobs (and KV caches) it
+    runs on it.
     """
-    return jax.jit(functools.partial(lm.init, cfg))(
+    return jax.jit(functools.partial(serve_weights, cfg))(
         jax.random.PRNGKey(weight_seed))
 
 

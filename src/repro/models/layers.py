@@ -3,7 +3,9 @@
 Conventions:
 
 * Parameters are stored in fp32 and cast to ``compute_dtype`` (bf16 by
-  default) at use; optimizer state stays fp32.
+  default) at use; optimizer state stays fp32.  The served weight set
+  (``core.jobs.serve_weights``) holds the matrix weights in the compute
+  dtype already, so there the cast is a no-op.
 * Layer-stacked parameters carry a leading ``[n_layers, ...]`` axis and are
   consumed by ``jax.lax.scan`` so the lowered HLO stays compact for the
   512-device dry-run.

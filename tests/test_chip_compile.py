@@ -116,7 +116,8 @@ def test_pallas_kernel_compiles_for_v5e(compile_v5e, name):
 def _served_structs(layers, batch, prompt_len, max_seq):
     import dataclasses
     cfg = dataclasses.replace(get_arch("yi-6b"), n_layers=layers)
-    params = jax.eval_shape(lambda: lm.init(cfg, jax.random.PRNGKey(0)))
+    params = jax.eval_shape(
+        lambda: jobs.serve_weights(cfg, jax.random.PRNGKey(0)))
     tokens = _sds((batch, prompt_len), jnp.int32)
     _, caches = jax.eval_shape(
         lambda p, t: lm.prefill(cfg, p, t, max_seq=max_seq), params, tokens)
