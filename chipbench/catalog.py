@@ -3,10 +3,14 @@
 A cell of ``BENCHMARK.json`` names a configuration and a traffic mix. The
 harness reads them from ``configs/<config>.json`` and
 ``traffic/<traffic>.json``, the limits of its output check from
-``checks/<cell>.json``, the step's operation and byte counts from
-``counts/<step_kind>.py``, its plain reference from
-``reference/<step_kind>.py`` and every metric from ``metrics/<metric>.py``. Adding a configuration, a mix, a cell or a metric
-adds files and entries; it edits none of the files that are there.
+``checks/<cell>.json`` and every metric from ``metrics/<metric>.py``. The
+configuration's ``step_kind`` names the rest: the program's configuration
+built from the file's published keys, with the check of the structure the
+step needs (``steps/<step_kind>.py``), the step's operation and byte
+counts (``counts/<step_kind>.py``) and its plain reference
+(``reference/<step_kind>.py``). Adding a configuration, a mix, a cell, a
+metric or a step kind adds files and entries; it edits none of the files
+that are there.
 """
 
 from __future__ import annotations
@@ -65,6 +69,9 @@ class Cell:
     end_to_end: List[Dict] = field(default_factory=list)
     per_layer: List[Dict] = field(default_factory=list)
     bench_dir: Path = BENCH_DIR
+
+    def step(self) -> ModuleType:
+        return load_module("steps", self.config["step_kind"], self.bench_dir)
 
     def counts(self) -> ModuleType:
         return load_module("counts", self.config["step_kind"], self.bench_dir)

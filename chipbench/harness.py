@@ -10,7 +10,6 @@ taken on the host's clock (``time.perf_counter``).
 from __future__ import annotations
 
 import asyncio
-import dataclasses
 import gc
 import json
 import shutil
@@ -42,38 +41,9 @@ SAMPLE_JOBS = 3
 #: Program modules whose device time the trace reduction reads.
 MODULES = ("jit__decode", "jit__prefill")
 
-#: Keys of a configuration file and the program's ``ArchConfig`` fields.
-CONFIG_FIELDS = {
-    "vocab_size": "vocab_size",
-    "hidden_size": "d_model",
-    "num_hidden_layers": "n_layers",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "intermediate_size": "d_ff",
-    "head_dim": "head_dim",
-    "rope_theta": "rope_theta",
-}
-
 
 def log(*parts) -> None:
     print("[chipbench]", *parts, file=sys.stderr, flush=True)
-
-
-def program_config(cfg: Dict):
-    """The program's configuration for a configuration file: the widths and
-    depth of the file over the program's entry for ``arch``."""
-    from repro.configs import get_arch
-    base = get_arch(cfg["arch"])
-    changes = {f: cfg[k] for k, f in CONFIG_FIELDS.items() if k in cfg}
-    pcfg = dataclasses.replace(base, **changes)
-    want = dict(family="dense", attn_kind="gqa", act="swiglu", norm="rms",
-                tie_embeddings=False, moe=None, ssm=None, rglru=None,
-                encoder=None)
-    for k, v in want.items():
-        if getattr(pcfg, k) != v:
-            raise ValueError(f"{cfg['arch']}: {k}={getattr(pcfg, k)!r}, the "
-                             f"{cfg['step_kind']} step needs {v!r}")
-    return pcfg
 
 
 def load_peaks(device_kind: str, bench_dir: Path = BENCH_DIR) -> Dict:
@@ -321,7 +291,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     serving = cell.config["serving"]
     traffic = cell.traffic
-    pcfg = program_config(cell.config)
+    pcfg = cell.step().program_config(cell.config)
     batch, prompt_len = int(traffic["batch"]), int(traffic["prompt_len"])
     tpb = int(serving["tokens_per_block"])
     weight_seed = tr.derived_seed(seed, tr.WEIGHTS)
@@ -406,7 +376,6 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
             f"{ctx.trace.busy_s!r}s, modules {ctx.trace.module_n} "
             f"{ctx.trace.module_s}, idle by host span "
             f"{ctx.trace.idle_by_label}")
-        breakdown = ctx.trace.breakdown()
 
     wanted = cell.per_layer if trace else cell.end_to_end
     metrics = {}
@@ -414,6 +383,13 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
         value = cell.reader(m["name"]).read(ctx)
         if value is not None:
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    if trace:
+        # After the readers: one that read a scope had the HLO protos
+        # parsed, and the breakdown then names what no instruction answers.
+        if ctx.trace.scopes_read:
+            log(f"trace: device time no HLO instruction answers, per "
+                f"execution: {ctx.trace.unattributed_s}")
+        breakdown = ctx.trace.breakdown()
 
     _log_summary(ctx, setup_compiles, window_compiles, check_s, check)
     dev = {"platform": device.platform, "kind": device.device_kind,

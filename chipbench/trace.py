@@ -22,16 +22,30 @@ copies overlap the computation and do not make the device busy alone.
 Busy time is averaged over the TPU planes that ran anything.
 A module that was asked for and never ran is reported as 0 executions, so
 that a reader that expected it can fail loudly rather than read 0.
+
+Device time by named scope (``Reduction.scope_s``): each operation's event
+is named by its HLO instruction, and the module run that holds it names
+the compiled module (``jit__decode(1939267968782906557)``); the
+``/host:metadata`` plane keeps one ``Hlo Proto`` for each such run, whose
+instructions carry the scope path of the program's source
+(``metadata.op_name``, ``jit(_decode)/while/body/...``); one that the
+compiler made with no source operation (a copy, a convert hoisted out of
+a loop) has an empty path and falls outside every scope. The protos are
+parsed on the first ``scope_s`` call only, with the protobuf modules of
+``XPLANE_PB2`` and ``HLO_PB2``; a reduction that is asked for no scope
+never imports them.
 """
 
 from __future__ import annotations
 
 import bisect
 import glob
+import importlib
 import os
 from collections import defaultdict
 from dataclasses import dataclass, field
-from typing import Dict, Iterable, List, Sequence, Tuple
+from pathlib import Path
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from chipbench.spans import BLOCK, WARMUP, WINDOW_CLOSE, WINDOW_OPEN
 
@@ -39,7 +53,14 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 DEVICE_PREFIX = "/device:TPU:"
 HOST_PREFIX = "/host:"
+METADATA_PLANE = "/host:metadata"
+HLO_PROTO = "Hlo Proto"
+XPLANE_PB2 = "tensorflow.tsl.profiler.protobuf.xplane_pb2"
+HLO_PB2 = "tensorflow.compiler.xla.service.hlo_pb2"
 TOP = 10
+#: Share of a module's time that may go unattributed to an instruction
+#: before the breakdown names it.
+UNATTRIBUTED_SHARE = 0.01
 
 
 class TraceError(RuntimeError):
@@ -55,6 +76,17 @@ class Reduction:
     ops: List[Tuple[str, float]] = field(default_factory=list)
     idle_gaps: List[Tuple[str, float]] = field(default_factory=list)
     idle_by_label: Dict[str, float] = field(default_factory=dict)
+    #: TPU planes that ran anything (``ops`` and busy time average over
+    #: them).
+    planes: int = 1
+    #: Self time, summed over the planes, of each (module run, instruction)
+    #: of each module asked for: what ``scope_s`` attributes.
+    instructions: Dict[str, Dict[Tuple[str, str], float]] = field(
+        default_factory=dict, repr=False)
+    #: The serialized trace, whose HLO protos ``scope_s`` reads.
+    xspace: Optional[bytes] = field(default=None, repr=False)
+    _op_names: Optional[Dict[str, Dict[str, str]]] = field(
+        default=None, repr=False)
 
     def module_mean_s(self, module: str) -> float:
         n = self.module_n.get(module, 0)
@@ -63,8 +95,58 @@ class Reduction:
                              f"window; modules seen: {self.module_n}")
         return self.module_s[module] / n
 
+    def _by_scope(self, module: str):
+        """(op name or None where no proto or instruction answers, total
+        self time) of each instruction of ``module`` that ran."""
+        self.module_mean_s(module)  # raises for a module that never ran
+        if self._op_names is None:
+            if self.xspace is None:
+                raise TraceError("the reduction holds no serialized trace "
+                                 "to read HLO protos from")
+            runs = {run for ops in self.instructions.values()
+                    for run, _ in ops}
+            self._op_names = hlo_op_names(self.xspace, runs)
+        for (run, inst), t in self.instructions[module].items():
+            yield self._op_names.get(run, {}).get(inst), t
+
+    def scope_s(self, module: str, scope: str) -> float:
+        """Mean device self time per execution of ``module`` of the
+        operations whose HLO ``op_name`` holds the path segment ``scope``
+        (``while`` holds a ``jax.lax`` loop's body, a ``jax.named_scope``
+        its name). Parses the trace's HLO protos on the first call."""
+        total = sum(t for name, t in self._by_scope(module)
+                    if name is not None and scope in name.split("/"))
+        return total / self.module_n[module]
+
+    @property
+    def unattributed_s(self) -> Dict[str, float]:
+        """For each module asked for that ran: the mean device self time
+        per execution of its operations that no HLO proto or instruction
+        answers, which no scope can hold. Parses the protos, as
+        ``scope_s`` does."""
+        return {m: sum(t for name, t in self._by_scope(m) if name is None)
+                / self.module_n[m]
+                for m in self.instructions if self.module_n[m]}
+
+    @property
+    def scopes_read(self) -> bool:
+        """Whether the HLO protos were parsed (by ``scope_s``)."""
+        return self._op_names is not None
+
     def breakdown(self) -> Dict:
-        return {"device_ops": [[n, s] for n, s in self.ops],
+        """The top device operations and idle gaps. Once a scope was read,
+        a module's time that no instruction answers is named among the
+        operations where it passes ``UNATTRIBUTED_SHARE`` of the module's."""
+        ops = self.ops
+        if self.scopes_read:
+            extra = [(f"{m}: no HLO instruction", s * self.module_n[m]
+                      / self.planes)
+                     for m, s in self.unattributed_s.items()
+                     if s > UNATTRIBUTED_SHARE * self.module_mean_s(m)]
+            if extra:
+                ops = sorted(ops[:TOP - len(extra)] + extra,
+                             key=lambda kv: -kv[1])
+        return {"device_ops": [[n, s] for n, s in ops],
                 "idle_gaps": [[n, s] for n, s in self.idle_gaps]}
 
 
@@ -188,18 +270,62 @@ def _self_times(named):
     return named, own
 
 
+def _instruction_name(name: str) -> str:
+    """``%convert.19 = bf16[...] convert(...)`` as ``convert.19``."""
+    return name.partition(" = ")[0].strip().lstrip("%")
+
+
 def _module_at(modules, starts: List[float], t: float) -> str:
+    """The full name of the module run holding ``t``, or ``?``."""
     i = bisect.bisect_right(starts, t) - 1
     if i >= 0 and modules[i][1] >= t:
         return modules[i][2]
     return "?"
 
 
+def _proto_module(name: str):
+    try:
+        return importlib.import_module(name)
+    except ImportError as exc:
+        raise TraceError(f"cannot import {name}, which reads the trace's "
+                         f"HLO protos: {exc}") from exc
+
+
+def hlo_op_names(xspace: bytes, runs: Iterable[str]
+                 ) -> Dict[str, Dict[str, str]]:
+    """For each module run named in ``runs`` whose ``Hlo Proto`` the
+    trace's metadata plane holds: its instructions' ``metadata.op_name``
+    by instruction name."""
+    xplane_pb2 = _proto_module(XPLANE_PB2)
+    hlo_pb2 = _proto_module(HLO_PB2)
+    runs = set(runs)
+    out: Dict[str, Dict[str, str]] = {}
+    for plane in xplane_pb2.XSpace.FromString(xspace).planes:
+        if plane.name != METADATA_PLANE:
+            continue
+        proto_ids = {k for k, m in plane.stat_metadata.items()
+                     if m.name == HLO_PROTO}
+        for meta in plane.event_metadata.values():
+            if meta.name not in runs:
+                continue
+            for stat in meta.stats:
+                if stat.metadata_id in proto_ids:
+                    module = hlo_pb2.HloProto.FromString(
+                        stat.bytes_value).hlo_module
+                    out[meta.name] = {
+                        i.name: i.metadata.op_name
+                        for c in module.computations
+                        for i in c.instructions}
+    return out
+
+
 def reduce(profile, modules: Sequence[str],
-           in_system: Sequence[Tuple[float, float]] = ()) -> Reduction:
+           in_system: Sequence[Tuple[float, float]] = (),
+           xspace: Optional[bytes] = None) -> Reduction:
     """Reduce a ``ProfileData`` to the window between the markers.
     ``in_system`` holds the intervals, in seconds after the window opened,
-    in which some job was in the system (they name idle gaps)."""
+    in which some job was in the system (they name idle gaps); ``xspace``
+    is the serialized trace, which ``Reduction.scope_s`` reads."""
     planes = list(profile.planes)
     lo, hi = _markers(planes)
     window_s = (hi - lo) / 1e9
@@ -209,12 +335,14 @@ def reduce(profile, modules: Sequence[str],
     module_s: Dict[str, float] = defaultdict(float)
     module_n: Dict[str, int] = defaultdict(int)
     ops: Dict[str, float] = defaultdict(float)
+    instructions: Dict[str, Dict[Tuple[str, str], float]] = {
+        m: defaultdict(float) for m in modules}
     devices = [p for p in planes if p.name.startswith(DEVICE_PREFIX)]
     if not devices:
         raise TraceError(f"no TPU plane in the trace: "
                          f"{[p.name for p in planes]}")
     for plane in devices:
-        runs = sorted((ev.start_ns, ev.end_ns, ev.name.split("(")[0])
+        runs = sorted((ev.start_ns, ev.end_ns, ev.name)
                       for ev in _events(plane, MODULES_LINE))
         run_starts = [r[0] for r in runs]
         for a, b, name in runs:
@@ -231,9 +359,12 @@ def reduce(profile, modules: Sequence[str],
             continue
         intervals = [(a, b) for a, b, _ in named]
         for (a, _, name), t in zip(*_self_times(named)):
-            key = (_module_at(runs, run_starts, a) + ": "
-                   + short_op_name(name))
+            run = _module_at(runs, run_starts, a)
+            key = run.split("(")[0] + ": " + short_op_name(name)
             ops[key] += t / 1e9
+            m = _module_of(run, modules)
+            if m is not None:
+                instructions[m][run, _instruction_name(name)] += t / 1e9
         busy = _union(intervals)
         busy_planes.append(sum(b - a for a, b in busy) / 1e9)
         edges = [lo] + [t for iv in busy for t in iv] + [hi]
@@ -257,7 +388,9 @@ def reduce(profile, modules: Sequence[str],
                    key=lambda kv: -kv[1])[:TOP],
         idle_gaps=[(f"{label} at {at:.3f}s", s)
                    for s, label, at in labelled[:TOP]],
-        idle_by_label=dict(idle_by_label))
+        idle_by_label=dict(idle_by_label), planes=n,
+        instructions={m: dict(v) for m, v in instructions.items()},
+        xspace=xspace)
 
 
 def xplane_files(directory: str) -> List[str]:
@@ -271,4 +404,6 @@ def reduce_dir(directory: str, modules: Sequence[str],
     files = xplane_files(directory)
     if not files:
         raise TraceError(f"no .xplane.pb under {directory}")
-    return reduce(ProfileData.from_file(files[-1]), modules, in_system)
+    raw = Path(files[-1]).read_bytes()
+    return reduce(ProfileData.from_serialized_xspace(raw), modules,
+                  in_system, xspace=raw)

@@ -16,6 +16,7 @@ def test_every_cell_of_the_benchmark_finds_its_files():
         assert cell.config["name"] == w["config"]
         assert cell.checks is not None, w["name"]
         cell.counts(), cell.reference()
+        assert callable(cell.step().program_config)
         for m in cell.end_to_end + cell.per_layer:
             assert callable(cell.reader(m["name"]).read)
     names = [c["name"] for c in bench["configs"]]
