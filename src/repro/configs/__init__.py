@@ -4,6 +4,8 @@ from .base import (
     MLAConfig,
     MoEConfig,
     RGLRUConfig,
+    RopeScaling,
+    ScaledRopeArchConfig,
     SSMConfig,
 )
 from .registry import ARCHS, get_arch
@@ -17,7 +19,9 @@ __all__ = [
     "MLAConfig",
     "MoEConfig",
     "RGLRUConfig",
+    "RopeScaling",
     "SHAPES",
+    "ScaledRopeArchConfig",
     "SSMConfig",
     "get_arch",
     "shapes_for",

@@ -21,9 +21,28 @@ class MoEConfig:
     n_experts: int
     top_k: int
     n_shared: int = 0             # shared (always-on) experts, DeepSeek-style
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # the expert-parallel path's capacity
     first_dense_layers: int = 0   # leading layers that keep a dense FFN
     d_ff_dense: Optional[int] = None  # FFN width of those dense layers
+    norm_topk_prob: bool = True   # renormalise the top-k gates to sum to 1
+    routed_scaling_factor: float = 1.0  # gate scale where not renormalised
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rotary scaling (arXiv:2309.00071), as DeepSeek-V2's
+    ``rope_scaling`` states it: frequencies interpolated by ``factor``
+    below the correction range that ``beta_fast``/``beta_slow`` rotations
+    over ``original_max_position`` positions set, and an attention
+    temperature from ``mscale``/``mscale_all_dim``.  An architecture that
+    publishes one holds it in :class:`ScaledRopeArchConfig`."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -91,6 +110,10 @@ class ArchConfig:
     tie_embeddings: bool = False
     sub_quadratic: bool = False   # eligible for long_500k
     source: str = ""              # provenance note
+
+    #: Rotary scaling: none here (a class attribute, not a field); a field
+    #: of :class:`ScaledRopeArchConfig`, which carries it where published.
+    rope_scaling = None
 
     # ------------------------------------------------------------- derived
     @property
@@ -215,3 +238,14 @@ class ArchConfig:
         base = shrink(self)
         base.update(overrides)
         return dataclasses.replace(self, **base)
+
+
+@dataclass(frozen=True)
+class ScaledRopeArchConfig(ArchConfig):
+    """An :class:`ArchConfig` whose rotary embedding is scaled, as the
+    architecture's ``rope_scaling`` publishes it.  The dense architectures
+    keep :class:`ArchConfig` and its field set, which
+    ``tests/chipbench/test_chipbench_steps.py`` pins field for field; a
+    change of those pins may fold this field into :class:`ArchConfig`."""
+
+    rope_scaling: Optional[RopeScaling] = None

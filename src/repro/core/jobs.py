@@ -106,8 +106,12 @@ def make_train_job(
 
 # The leaves that a served step reads only through ``cast(leaf, compute
 # dtype)``: the matrix-product weights of linears, MLPs and attention, the
-# head and the embedding table.
-_COMPUTE_DTYPE_LEAVES = frozenset({"w", "wq", "wk", "wv", "wo", "table"})
+# head and the embedding table; the latent attention's projections; the
+# experts and their router.
+_COMPUTE_DTYPE_LEAVES = frozenset({
+    "w", "wq", "wk", "wv", "wo", "table",
+    "wdq", "wuq", "wdkv", "wkr", "wuk", "wuv",
+    "gate_w", "up_w", "down_w", "router"})
 
 
 def _served_leaf(path, leaf: jnp.ndarray) -> jnp.ndarray:
@@ -118,17 +122,19 @@ def _served_leaf(path, leaf: jnp.ndarray) -> jnp.ndarray:
 
 def serve_weights(cfg: ArchConfig, key) -> Dict:
     """The float32 set of :func:`lm.init`, with each matrix-product weight
-    (``w`` of linears and MLPs, ``wq``/``wk``/``wv``/``wo``, ``lm_head.w``)
-    and the embedding table held in the compute dtype (bfloat16).
+    (``w`` of linears and MLPs, ``wq``/``wk``/``wv``/``wo``, ``lm_head.w``,
+    the latent attention's ``wdq``/``wuq``/``wdkv``/``wkr``/``wuk``/``wuv``,
+    the experts' ``gate_w``/``up_w``/``down_w`` and their ``router``) and
+    the embedding table held in the compute dtype (bfloat16).
 
     The served steps read these leaves only through ``cast(leaf, compute
     dtype)``, so rounding them once here feeds every matrix product the
     same bits as rounding them on every step, and a step converts no
     weight.  Every other leaf stays float32 as drawn: the norm scales and
     the recurrent mixers' decay and step parameters, which the steps read
-    in float32, and the biases and the latent and recurrent mixers' own
-    projections, which they cast at use as before.  Training keeps its
-    float32 masters (:func:`make_train_job`).
+    in float32, and the biases and the recurrent mixers' own projections,
+    which they cast at use as before.  Training keeps its float32 masters
+    (:func:`make_train_job`).
     """
     return jax.tree_util.tree_map_with_path(_served_leaf, lm.init(cfg, key))
 
@@ -219,6 +225,10 @@ def make_serve_job(
                     logits, caches = _prefill(cfg, params, prompt,
                                               max_seq=max_seq)
                     state["caches"] = caches
+                    if tracing.enabled():
+                        tracing.count("job.cache_bytes", sum(
+                            leaf.nbytes for leaf in jax.tree.leaves(caches)),
+                            job=name)
                     state["lengths"] = jnp.full((batch,), prompt_len,
                                                 jnp.int32)
                     state["token"] = jnp.argmax(logits, -1)

@@ -6,7 +6,8 @@ Each op has up to three interchangeable implementations:
   (quadratic / sequential; ground truth),
 * ``backend="xla"``    — the efficient XLA formulation used by the
   distributed train/serve paths (online-softmax KV-chunk streaming for
-  attention, chunked SSD, associative-scan RG-LRU, sort-based MoE dispatch),
+  attention, chunked SSD, associative-scan RG-LRU, sorted grouped-product
+  MoE),
 * ``backend="pallas"`` — the Pallas TPU kernels (see flash_attention.py,
   ssd_scan.py, ...), compiled for the TPU; the tests validate them on CPU
   with ``interpret=True``.
@@ -18,7 +19,7 @@ sweeps in ``tests/test_kernels.py``.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -443,49 +444,80 @@ def moe_combine(y: jnp.ndarray, meta, T: int) -> jnp.ndarray:
         contrib, mode="drop")
 
 
+#: megablox ``gmm`` tiles, (rows, contraction, output), by the rows of a
+#: call: a decode step's few (rows None: its rows in one tile) and a
+#: prefill's many; the fastest of those ``chipbench/grouped_products.py``
+#: times on a v5e at DeepSeek-V2-Lite's expert widths.
+GMM_TILING_FEW = (None, 512, 1408)
+GMM_TILING_MANY = (256, 2048, 512)
+#: Rows up to which a grouped product counts as few.
+GMM_FEW_ROWS = 512
+
+
+def grouped_product(lhs: jnp.ndarray, rhs: jnp.ndarray, sizes: jnp.ndarray,
+                    *, tiling: Optional[Tuple[Optional[int], int, int]] = None
+                    ) -> jnp.ndarray:
+    """Rows ``lhs[m, k]``, sorted by group, times their group's matrix of
+    ``rhs[G, k, n]``; ``sizes[G]`` counts each group's rows.  On a TPU the
+    megablox kernel (``gmm``), which reads the matrices of the groups that
+    hold rows and no other, with ``tiling`` (rows, contraction, output;
+    rows None: the call's rows in one tile), by default the one for the
+    call's rows above; elsewhere ``jax.lax.ragged_dot``."""
+    if jax.default_backend() != "tpu":
+        return jax.lax.ragged_dot(lhs, rhs, sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, k = lhs.shape
+    if tiling is None:
+        tiling = GMM_TILING_FEW if m <= GMM_FEW_ROWS else GMM_TILING_MANY
+    tm, tk, tn = tiling
+    tm = tm or -(-m // 16) * 16
+    pad = -m % tm
+    out = gmm(jnp.pad(lhs, ((0, pad), (0, 0))), rhs, sizes,
+              preferred_element_type=lhs.dtype,
+              tiling=(tm, min(tk, k), min(tn, rhs.shape[2])))
+    return out[:m]
+
+
 def moe_apply(
     x: jnp.ndarray,          # [T, D] flattened tokens
-    gate_w: jnp.ndarray,     # [E, D, F]
-    up_w: jnp.ndarray,       # [E, D, F]
-    down_w: jnp.ndarray,     # [E, F, D]
+    gate_w: jnp.ndarray,     # [E, D, F], or [L, E, D, F] with ``layer``
+    up_w: jnp.ndarray,       # [E, D, F], or [L, E, D, F]
+    down_w: jnp.ndarray,     # [E, F, D], or [L, E, F, D]
     topk_idx: jnp.ndarray,   # [T, K] int32
     topk_gate: jnp.ndarray,  # [T, K] float
-    capacity: int,
     *,
+    layer: Optional[jnp.ndarray] = None,
     dtype=jnp.bfloat16,
 ) -> jnp.ndarray:
-    """Capacity-based sort dispatch MoE (TPU-native GShard-style, but with
-    sort instead of one-hot so long sequences stay feasible)."""
+    """Drop-free routed SwiGLU: the ``T*K`` (token, expert) assignments are
+    sorted by expert over the whole batch, each expert's rows form one group
+    of a grouped product (:func:`grouped_product`), and each token's ``K``
+    outputs are summed with its gates.  Every assignment is computed at any
+    load, all tokens on one expert included.
+
+    With ``layer``, the weights are a stack of ``L`` layers' experts and
+    the products read layer ``layer``'s from it in place: the stack is
+    ``L*E`` groups, of which only that layer's hold rows (a slice of the
+    stack would be copied for the kernel)."""
     T, D = x.shape
-    E, _, F = gate_w.shape
     K = topk_idx.shape[1]
-    TK = T * K
-
+    if layer is not None:
+        L, E = gate_w.shape[:2]
+        gate_w, up_w, down_w = (w.reshape((L * E,) + w.shape[2:])
+                                for w in (gate_w, up_w, down_w))
+        topk_idx = topk_idx + layer * E
     flat_e = topk_idx.reshape(-1)                        # [TK]
-    flat_t = jnp.repeat(jnp.arange(T, dtype=jnp.int32), K)
-    flat_g = topk_gate.reshape(-1)
-
     order = jnp.argsort(flat_e, stable=True)
-    se = flat_e[order]
-    st = flat_t[order]
-    sg = flat_g[order]
+    rows = x[order // K].astype(dtype)                   # [TK, D] by group
+    sizes = jnp.bincount(flat_e, length=gate_w.shape[0]).astype(jnp.int32)
 
-    # position of each entry within its expert's segment
-    first = jnp.searchsorted(se, se, side="left")
-    pos = jnp.arange(TK, dtype=jnp.int32) - first.astype(jnp.int32)
-    keep = pos < capacity
-    pos_c = jnp.where(keep, pos, 0)
-    e_c = jnp.where(keep, se, 0)
-
-    gathered = x[st] * keep[:, None].astype(x.dtype)
-    buf = jnp.zeros((E, capacity, D), x.dtype)
-    buf = buf.at[e_c, pos_c].add(gathered, mode="drop")
-
-    h = jnp.einsum("ecd,edf->ecf", buf, gate_w.astype(dtype))
-    u = jnp.einsum("ecd,edf->ecf", buf, up_w.astype(dtype))
+    h = grouped_product(rows, gate_w.astype(dtype), sizes)
+    u = grouped_product(rows, up_w.astype(dtype), sizes)
     h = jax.nn.silu(h.astype(jnp.float32)).astype(dtype) * u
-    y = jnp.einsum("ecf,efd->ecd", h, down_w.astype(dtype))
+    y = grouped_product(h, down_w.astype(dtype), sizes)
 
-    contrib = y[e_c, pos_c] * (sg * keep).astype(y.dtype)[:, None]
-    out = jnp.zeros((T, D), y.dtype).at[st].add(contrib, mode="drop")
+    y = y[jnp.argsort(order)].reshape(T, K, D)           # token order
+    out = jnp.einsum("tkd,tk->td", y.astype(jnp.float32),
+                     topk_gate.astype(jnp.float32))
     return out.astype(x.dtype)

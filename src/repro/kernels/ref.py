@@ -145,8 +145,8 @@ def moe_dense(
 ) -> jnp.ndarray:
     """Dense-einsum MoE oracle: every token through every expert, weighted.
 
-    O(T*E*D*F) — only usable at test sizes; the efficient path uses
-    capacity-based dispatch (ops.moe_apply).
+    O(T*E*D*F) — only usable at test sizes; the efficient path sorts the
+    assignments by expert into grouped products (ops.moe_apply).
     """
     xf = x.astype(jnp.float32)
     h = jnp.einsum("td,edf->tef", xf, gate_w.astype(jnp.float32))

@@ -302,7 +302,7 @@ def build_unit_probes(cfg: ArchConfig, shape: InputShape, mesh=None,
 
             def probe(up, c, x, lengths, stage=stage):
                 return lm.decode_unit(cfg, stage, up, c, x, lengths,
-                                      backend=backend)
+                                      backend=backend, shard=shard)
 
             args = (u_struct, c_struct, xd_struct, len_struct)
             if mesh is not None:

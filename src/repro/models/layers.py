@@ -19,6 +19,7 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 DEFAULT_COMPUTE_DTYPE = jnp.bfloat16
 
@@ -98,6 +99,51 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
     sin = jnp.sin(angles)[..., :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term, ``0.1 * mscale * ln(factor) + 1``
+    (1 where nothing is interpolated)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_rope_freqs(head_dim: int, theta: float, scaling) -> np.ndarray:
+    """Inverse frequencies ``[head_dim / 2]`` of YaRN rotary scaling
+    (``configs.base.RopeScaling``), as DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding`` computes them: dimensions that turn
+    more than ``beta_fast`` times over the original context keep their
+    frequency, those that turn fewer than ``beta_slow`` times are divided
+    by ``factor``, and a linear ramp blends the two between."""
+    def correction_dim(rotations):
+        return (head_dim * math.log(scaling.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / theta ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                            / head_dim)
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    return (extra / scaling.factor * ramp + extra * (1.0 - ramp)
+            ).astype(np.float32)
+
+
+def apply_rope_pairs(x: jnp.ndarray, positions: jnp.ndarray,
+                     inv_freq: np.ndarray, scale: float = 1.0) -> jnp.ndarray:
+    """Rotary embedding over interleaved pairs ``(x[2i], x[2i+1])`` turned
+    by ``positions * inv_freq[i]``, with cos and sin times ``scale``; the
+    result holds the pairs' first members, then their second (the layout
+    of DeepSeek-V2's ``apply_rotary_pos_emb``).  x: [..., S, H, D]."""
+    angles = positions[..., :, None].astype(jnp.float32) * inv_freq
+    cos = (jnp.cos(angles) * scale)[..., :, None, :]
+    sin = (jnp.sin(angles) * scale)[..., :, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
 
 

@@ -169,7 +169,8 @@ def _apply_layer(cfg: ArchConfig, spec: LayerSpec, p: Dict, x, *,
     elif spec.mixer == "mla":
         mix, kv = mla_mod.mla_apply(
             p["mixer"], h, cfg.mla, rope_theta=cfg.rope_theta,
-            positions=positions, backend=backend, shard=shard, dtype=dtype)
+            rope_scaling=cfg.rope_scaling, positions=positions,
+            backend=backend, shard=shard, dtype=dtype)
         cache = _pad_mla(kv, max_seq)
     elif spec.mixer == "ssd":
         mix, cache = ssm_mod.mamba2_apply(
@@ -238,6 +239,41 @@ def _pad_mla(kv: Dict, max_seq: Optional[int]) -> Dict:
     return {"c_kv": kv["c_kv"], "k_rope": kv["k_rope"]}
 
 
+#: The stacked weights of a stage's routed experts.
+_EXPERT_LEAVES = ("gate_w", "up_w", "down_w")
+
+
+def _hold_experts(stage: Stage, stage_p: Dict, shard, hold: bool
+                  ) -> Tuple[Dict, Optional[Dict]]:
+    """In a served step on one device (``hold``, no mesh), take a stage's
+    stacked expert weights ``[repeats, E, ...]`` out of what its layer scan
+    slices: the grouped products read a layer's experts from the whole
+    stack (``ops.moe_apply``), where a slice of the stack would be copied
+    for the kernel in every layer and step.  Where gradients are taken the
+    scan slices them, as every other weight, so that no layer's gradient
+    spans the whole stack.  Returns (the rest of the stage's parameters,
+    the held weights by unit, or None where nothing is held)."""
+    moe_units = [f"u{ui}" for ui, spec in enumerate(stage.unit)
+                 if spec.ffn == "moe"]
+    if not (hold and moe_units) or getattr(shard, "mesh", None) is not None:
+        return stage_p, None
+    rest, held = dict(stage_p), {}
+    for u in moe_units:
+        ffn = dict(rest[u]["ffn"])
+        held[u] = {k: ffn.pop(k) for k in _EXPERT_LEAVES}
+        rest[u] = {**rest[u], "ffn": ffn}
+    return rest, held
+
+
+def _with_held(repeat_p: Dict, held: Dict, layer) -> Dict:
+    """One repeat's parameters with its units' held expert stacks and the
+    repeat's index into them (``ffn["layer"]``)."""
+    out = dict(repeat_p)
+    for u, w in held.items():
+        out[u] = {**out[u], "ffn": {**out[u]["ffn"], **w, "layer": layer}}
+    return out
+
+
 def encoder_unit(cfg: ArchConfig, p: Dict, x, *, backend="xla",
                  shard=NULL_SHARDER, dtype=DEFAULT_COMPUTE_DTYPE):
     """One encoder layer (the encoder scan body)."""
@@ -297,10 +333,13 @@ def forward(
     shard=NULL_SHARDER,
     remat: bool = False,
     return_hidden: bool = False,
+    hold_experts: bool = False,
     dtype=DEFAULT_COMPUTE_DTYPE,
 ) -> Tuple:
     """Returns (logits [B,S,V] — or final hidden states if
-    ``return_hidden``, for the vocab-chunked loss — , aux, caches|None)."""
+    ``return_hidden``, for the vocab-chunked loss — , aux, caches|None).
+    ``hold_experts`` (served prefill, no gradient): the expert layers read
+    their weights in place from the stage's stack (``_hold_experts``)."""
     plan = build_plan(cfg)
     x = embed(params["embed"], tokens, dtype)
     if patches is not None:
@@ -318,10 +357,13 @@ def forward(
     caches: Dict = {}
     aux_total = jnp.zeros((), jnp.float32)
     for si, stage in enumerate(plan):
-        stage_p = params[f"stage{si}"]
+        stage_p, held = _hold_experts(stage, params[f"stage{si}"], shard,
+                                      hold_experts)
 
-        def body(carry, repeat_p, stage=stage):
+        def body(carry, xs, stage=stage, held=held):
             x, aux = carry
+            repeat_p = (xs if held is None
+                        else _with_held(xs[0], held, xs[1]))
             x, entries, a = apply_unit(
                 cfg, stage, repeat_p, x, enc_out=enc_out,
                 positions=positions, max_seq=cache_seq, backend=backend,
@@ -329,7 +371,9 @@ def forward(
             return (x, aux + a), (entries if collect_cache else None)
 
         body_fn = jax.checkpoint(body) if remat else body
-        (x, aux_total), ys = jax.lax.scan(body_fn, (x, aux_total), stage_p)
+        xs = (stage_p if held is None
+              else (stage_p, jnp.arange(stage.repeats)))
+        (x, aux_total), ys = jax.lax.scan(body_fn, (x, aux_total), xs)
         if collect_cache:
             caches[f"stage{si}"] = ys
 
@@ -431,7 +475,7 @@ def prefill(cfg: ArchConfig, params: Dict, tokens, *, max_seq: int,
     hidden, _, caches = forward(
         cfg, params, tokens, patches=patches, enc_frames=enc_frames,
         collect_cache=True, max_seq=max_seq, backend=backend, shard=shard,
-        return_hidden=True, dtype=dtype)
+        return_hidden=True, hold_experts=True, dtype=dtype)
     last = hidden[:, -1, :]
     if cfg.tie_embeddings:
         logits = last @ params["embed"]["table"].astype(dtype).T
@@ -442,7 +486,7 @@ def prefill(cfg: ArchConfig, params: Dict, tokens, *, max_seq: int,
 
 def decode_unit(cfg: ArchConfig, stage: Stage, repeat_p: Dict,
                 repeat_c: Dict, x, lengths, *, backend="xla",
-                dtype=DEFAULT_COMPUTE_DTYPE):
+                shard=NULL_SHARDER, dtype=DEFAULT_COMPUTE_DTYPE):
     """One repeat of a stage's unit in decode mode (the decode scan body).
     Returns (x, updated cache entries)."""
     new_entries = {}
@@ -459,7 +503,8 @@ def decode_unit(cfg: ArchConfig, stage: Stage, repeat_p: Dict,
         elif spec.mixer == "mla":
             mix, nc = mla_mod.mla_decode(
                 p["mixer"], h, {"c_kv": c["c_kv"], "k_rope": c["k_rope"]},
-                lengths, cfg.mla, rope_theta=cfg.rope_theta, dtype=dtype)
+                lengths, cfg.mla, rope_theta=cfg.rope_theta,
+                rope_scaling=cfg.rope_scaling, dtype=dtype)
         elif spec.mixer == "ssd":
             mix, nc = ssm_mod.mamba2_decode(
                 p["mixer"], h, c, cfg.ssm, cfg.d_model, dtype=dtype)
@@ -478,7 +523,8 @@ def decode_unit(cfg: ArchConfig, stage: Stage, repeat_p: Dict,
             if spec.ffn == "dense":
                 x = x + apply_mlp(p["ffn"], h2, cfg.act, dtype)[:, 0]
             else:
-                y, _ = moe_mod.moe_apply(p["ffn"], h2, cfg.moe, dtype=dtype)
+                y, _ = moe_mod.moe_apply(p["ffn"], h2, cfg.moe, shard=shard,
+                                         dtype=dtype)
                 x = x + y[:, 0]
         new_entries[f"u{ui}"] = nc
     return x, new_entries
@@ -501,15 +547,20 @@ def decode_step(
     x = shard.decode_activations(x)
     new_caches: Dict = {}
     for si, stage in enumerate(plan):
-        stage_p = params[f"stage{si}"]
+        stage_p, held = _hold_experts(stage, params[f"stage{si}"], shard,
+                                      True)
         stage_c = caches[f"stage{si}"]
 
-        def body(x, inp, stage=stage):
-            repeat_p, repeat_c = inp
+        def body(x, inp, stage=stage, held=held):
+            repeat_p, repeat_c = inp[:2]
+            if held is not None:
+                repeat_p = _with_held(repeat_p, held, inp[2])
             return decode_unit(cfg, stage, repeat_p, repeat_c, x, lengths,
-                               backend=backend, dtype=dtype)
+                               backend=backend, shard=shard, dtype=dtype)
 
-        x, new_stage_c = jax.lax.scan(body, x, (stage_p, stage_c))
+        xs = ((stage_p, stage_c) if held is None
+              else (stage_p, stage_c, jnp.arange(stage.repeats)))
+        x, new_stage_c = jax.lax.scan(body, x, xs)
         new_caches[f"stage{si}"] = new_stage_c
 
     x = apply_norm(params["final_norm"], x, cfg.norm)

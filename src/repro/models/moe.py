@@ -1,9 +1,13 @@
-"""Mixture-of-Experts FFN: top-k routing + capacity-based sort dispatch
-(ops.moe_apply), optional shared experts (DeepSeek-style), auxiliary
-load-balance loss.
+"""Mixture-of-Experts FFN: top-k routing, drop-free grouped products over
+the experts on one device (ops.moe_apply), capacity dispatch on a mesh
+(expert-parallel for a sequence, per batch row for decode), optional shared
+experts (DeepSeek-style), auxiliary load-balance loss.
 
 Expert weights are stacked ``[E, ...]`` so the expert axis shards over the
-``model`` mesh axis (expert parallelism).
+``model`` mesh axis (expert parallelism).  The layer runs under
+``jax.named_scope("moe")`` (router, dispatch, grouped products, combine and
+shared experts), so that a profile can time it apart from the rest of a
+layer.
 """
 
 from __future__ import annotations
@@ -35,6 +39,32 @@ def moe_init(key, d_model: int, d_ff: int, cfg: MoEConfig) -> Dict:
     if cfg.n_shared:
         p["shared"] = mlp_init(ks[4], d_model, cfg.n_shared * d_ff, "swiglu")
     return p
+
+
+def _expert_swiglu(buf, gw, uw, dw, dtype):
+    """Each expert's SwiGLU over its capacity buffer ``buf [E, C, D]``."""
+    h = jnp.einsum("ecd,edf->ecf", buf, cast(gw, dtype))
+    u = jnp.einsum("ecd,edf->ecf", buf, cast(uw, dtype))
+    h = jax.nn.silu(h.astype(jnp.float32)).astype(dtype) * u
+    return jnp.einsum("ecf,efd->ecd", h, cast(dw, dtype))
+
+
+def _moe_rows(p: Dict, x, idx, gate, cfg: MoEConfig, dtype):
+    """Capacity dispatch within each batch row, vmapped over the rows: a
+    form that GSPMD partitions over a mesh's batch axis (decode on a mesh),
+    where flattening the rows would have it replicate the activations.
+    A row's capacity is ``capacity_factor * top_k * S / E`` and at least 1,
+    so a row of one token (decode) keeps its ``top_k`` assignments, which
+    name distinct experts."""
+    S = x.shape[1]
+    cap = max(1, int(cfg.capacity_factor * cfg.top_k * S // cfg.n_experts))
+
+    def row(xr, ir, gr):
+        buf, meta = ops.moe_dispatch(xr, ir, gr, cfg.n_experts, cap)
+        y = _expert_swiglu(buf, p["gate_w"], p["up_w"], p["down_w"], dtype)
+        return ops.moe_combine(y, meta, S)
+
+    return jax.vmap(row)(x, idx, gate)
 
 
 def _moe_shard_map(p: Dict, x, idx, gate, cfg: MoEConfig, shard, dtype):
@@ -76,10 +106,7 @@ def _moe_shard_map(p: Dict, x, idx, gate, cfg: MoEConfig, shard, dtype):
             gw = jax.lax.all_gather(gw, w_data, axis=1, tiled=True)
             uw = jax.lax.all_gather(uw, w_data, axis=1, tiled=True)
             dw = jax.lax.all_gather(dw, w_data, axis=2, tiled=True)
-        h = jnp.einsum("ecd,edf->ecf", buf, cast(gw, dtype))
-        u = jnp.einsum("ecd,edf->ecf", buf, cast(uw, dtype))
-        h = jax.nn.silu(h.astype(jnp.float32)).astype(dtype) * u
-        y = jnp.einsum("ecf,efd->ecd", h, cast(dw, dtype))
+        y = _expert_swiglu(buf, gw, uw, dw, dtype)
         # expert outputs -> back to token shards
         y = jax.lax.all_to_all(y, model_axis, split_axis=1, concat_axis=0,
                                tiled=True)
@@ -98,6 +125,7 @@ def _moe_shard_map(p: Dict, x, idx, gate, cfg: MoEConfig, shard, dtype):
     return fn(x, idx, gate, p["gate_w"], p["up_w"], p["down_w"])
 
 
+@jax.named_scope("moe")
 def moe_apply(
     p: Dict,
     x: jnp.ndarray,              # [B, S, D]
@@ -108,30 +136,38 @@ def moe_apply(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (out [B,S,D], aux load-balance loss scalar).
 
-    Routing/dispatch is vmapped over the batch row: flattening B*S would
-    merge the batch-sharded and sequence-sharded axes and force SPMD to
-    replicate the activations (measured +100s/dev of all-gather and tens of
-    GiB on dbrx/deepseek — see EXPERIMENTS.md §Perf).  Per-row dispatch
-    keeps the batch axis data-parallel; capacity is per (row, expert).
+    Gates are the top-k softmax scores, renormalised to sum to one where
+    ``cfg.norm_topk_prob``, else scaled by ``cfg.routed_scaling_factor`` (as
+    DeepSeek-V2's gate does).  On one device every (token, expert)
+    assignment of the batch is computed (``ops.moe_apply``), from the
+    whole stack of the stage's experts where ``p["layer"]`` indexes it
+    (``lm._hold_experts``).  On a mesh a sequence goes through the
+    expert-parallel ``_moe_shard_map`` and one token a row (decode) through
+    ``_moe_rows``: both dispatch into per-expert capacity, which can drop a
+    sequence's assignments and keeps every one of a single token's.
     """
     B, S, D = x.shape
     logits = (x @ cast(p["router"], dtype)).astype(jnp.float32)   # [B,S,E]
     probs = jax.nn.softmax(logits, axis=-1)
     gate, idx = jax.lax.top_k(probs, cfg.top_k)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    else:
+        gate = gate * cfg.routed_scaling_factor
 
-    if shard is not None and getattr(shard, "mesh", None) is not None \
-            and S > 1:
+    on_mesh = shard is not None and getattr(shard, "mesh", None) is not None
+    if on_mesh and S > 1:
         y = _moe_shard_map(p, x, idx.astype(jnp.int32),
                            gate.astype(dtype), cfg, shard, dtype)
+    elif on_mesh:
+        y = _moe_rows(p, x, idx.astype(jnp.int32), gate.astype(dtype), cfg,
+                      dtype)
     else:
-        capacity = max(1, int(cfg.capacity_factor * cfg.top_k * S
-                              // cfg.n_experts))
-        y = jax.vmap(
-            lambda xr, ir, gr: ops.moe_apply(
-                xr, p["gate_w"], p["up_w"], p["down_w"], ir, gr, capacity,
-                dtype=dtype)
-        )(x, idx.astype(jnp.int32), gate.astype(dtype))
+        y = ops.moe_apply(
+            x.reshape(B * S, D), p["gate_w"], p["up_w"], p["down_w"],
+            idx.reshape(B * S, -1).astype(jnp.int32),
+            gate.reshape(B * S, -1), layer=p.get("layer"),
+            dtype=dtype).reshape(B, S, D)
     if "shared" in p:
         y = y + apply_mlp(p["shared"], x, "swiglu", dtype)
 

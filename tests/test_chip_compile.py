@@ -20,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_arch
 from repro.core import jobs
+from repro.kernels import ops
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.rglru_scan import rglru_pallas
@@ -31,6 +32,11 @@ V5E_HBM_BYTES = 16 * 10**9
 #: The served configuration of chip_smoke.py: yi-6b at published widths,
 #: 8 of its 32 layers; batch 8, prompt 512, a cache for 32 blocks of 8.
 YI_8_LAYERS = dict(layers=8, batch=8, prompt_len=512, max_seq=512 + 256 + 8)
+#: The served cut of the chip benchmark's deepseek-v2-lite-5L: the dense
+#: layer and 4 expert layers at published widths; batch 8, prompt 512, a
+#: cache for 16 blocks of 8.
+DSV2_5_LAYERS = dict(layers=5, batch=8, prompt_len=512,
+                     max_seq=512 + 128 + 8)
 
 
 @pytest.fixture(scope="module")
@@ -113,9 +119,22 @@ def test_pallas_kernel_compiles_for_v5e(compile_v5e, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _served_structs(layers, batch, prompt_len, max_seq):
+@pytest.mark.parametrize("rows", [48, 24576])
+def test_grouped_product_compiles_for_v5e(compile_v5e, monkeypatch, rows):
+    """The grouped product as ``ops.grouped_product`` runs it on a TPU
+    (megablox ``gmm``) at DeepSeek-V2-Lite's expert widths: the rows of a
+    decode step and of a prefill at batch 8, top-6, against a stack of four
+    layers' 64 experts of 2048 x 1408."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = compile_v5e(ops.grouped_product, _sds((rows, 2048)),
+                           _sds((4 * 64, 2048, 1408)),
+                           _sds((4 * 64,), jnp.int32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _served_structs(layers, batch, prompt_len, max_seq, arch="yi-6b"):
     import dataclasses
-    cfg = dataclasses.replace(get_arch("yi-6b"), n_layers=layers)
+    cfg = dataclasses.replace(get_arch(arch), n_layers=layers)
     params = jax.eval_shape(
         lambda: jobs.serve_weights(cfg, jax.random.PRNGKey(0)))
     tokens = _sds((batch, prompt_len), jnp.int32)
@@ -142,3 +161,50 @@ def test_served_yi6b_step_fits_one_v5e(compile_v5e, step):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, (step, used)
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_served_deepseek_step_fits_one_v5e(compile_v5e, monkeypatch, step):
+    """The served steps of the benchmark's DeepSeek-V2-Lite cut, with the
+    grouped products a TPU runs, compile for one chip and fit its memory;
+    the decode step copies no layer's experts out of their stack."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = dict(DSV2_5_LAYERS)
+    cfg, params, tokens, caches = _served_structs(
+        **shape, arch="deepseek-v2-lite-16b")
+    if step == "prefill":
+        compiled = compile_v5e(
+            lambda p, t: jobs._prefill(cfg, p, t, max_seq=shape["max_seq"]),
+            params, tokens)
+    else:
+        vec = _sds((shape["batch"],), jnp.int32)
+        compiled = compile_v5e(
+            lambda p, t, c, n: jobs._decode(cfg, p, t, c, n),
+            params, vec, caches, vec)
+        assert "bf16[64,2048,1408]" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < V5E_HBM_BYTES, (step, used)
+
+
+def test_deepseek_decode_on_a_v5e_mesh_compiles(topo, monkeypatch):
+    """DeepSeek-V2-Lite's decode step at published widths (the dense layer
+    and one expert layer) on a 2x2 v5e mesh: the expert layer takes the
+    per-row capacity dispatch there, which the compiler partitions, and no
+    grouped-product kernel, which it cannot."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+
+    from repro.configs.shapes import InputShape
+    from repro.launch.steps import build_decode_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    cfg = dataclasses.replace(get_arch("deepseek-v2-lite-16b"), n_layers=2)
+    bundle = build_decode_step(cfg, InputShape("d", 640, 8, "decode"),
+                               mesh=mesh)
+    compiled = bundle.fn.lower(*bundle.arg_specs).compile()
+    assert "tpu_custom_call" not in compiled.as_text()

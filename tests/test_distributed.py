@@ -67,21 +67,28 @@ l_local = float(lm.loss_fn(cfg, params, batch)[0])
 assert abs(l_sharded - l_local) < 5e-2, (l_sharded, l_local)
 print(f"OK moe shard_map == local: {l_sharded:.4f} vs {l_local:.4f}")
 
-# decode step on the mesh
-cfg = get_arch("yi-6b").reduced()
-cfg = dataclasses.replace(cfg, vocab_size=512)
-shape = InputShape("d", 32, 8, "decode")
-bundle = build_decode_step(cfg, shape, mesh=mesh)
-params = lm.init(cfg, jax.random.PRNGKey(1))
-prompt = jax.random.randint(jax.random.PRNGKey(2), (8, 16), 0, 512)
-with mesh:
+# decode step on the mesh, against the same step on one device (the
+# experts: per-row capacity dispatch on the mesh, grouped products alone)
+for arch_id in ["yi-6b", "deepseek-v2-lite-16b"]:
+    cfg = get_arch(arch_id).reduced()
+    cfg = dataclasses.replace(cfg, vocab_size=512)
+    shape = InputShape("d", 32, 8, "decode")
+    bundle = build_decode_step(cfg, shape, mesh=mesh)
+    params = lm.init(cfg, jax.random.PRNGKey(1))
+    prompt = jax.random.randint(jax.random.PRNGKey(2), (8, 16), 0, 512)
     logits, caches = jax.jit(
         lambda p, t: lm.prefill(cfg, p, t, max_seq=32))(params, prompt)
     tok = jnp.argmax(logits, -1)
     lengths = jnp.full((8,), 16, jnp.int32)
-    out, caches = bundle.fn(params, tok, caches, lengths)
-assert np.isfinite(np.asarray(out, np.float32)).all()
-print("OK decode yi-6b on mesh")
+    local, _ = jax.jit(lambda p, t, c, n: lm.decode_step(cfg, p, t, c, n))(
+        params, tok, caches, lengths)
+    with mesh:
+        out, _ = bundle.fn(params, tok, caches, lengths)
+    out, local = np.asarray(out, np.float32), np.asarray(local, np.float32)
+    assert np.isfinite(out).all(), arch_id
+    err = float(np.abs(out - local).max() / np.abs(local).max())
+    assert err < 2e-2, (arch_id, err)
+    print(f"OK decode {arch_id} on mesh == local: {err:.2e}")
 print("ALL_DISTRIBUTED_OK")
 """
 

@@ -253,8 +253,33 @@ def test_moe_no_drop_matches_dense_oracle():
     gate = gate / gate.sum(-1, keepdims=True)
     dense = jnp.zeros((T, E)).at[jnp.arange(T)[:, None], idx].set(gate)
     want = ref.moe_dense(x, gw, uw, dw, dense)
-    got = ops.moe_apply(x, gw, uw, dw, idx, gate, capacity=T,
-                        dtype=jnp.float32)
+    got = ops.moe_apply(x, gw, uw, dw, idx, gate, dtype=jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("layer", [None, 2])
+def test_moe_all_assignments_on_one_expert_drop_none(layer):
+    """Every token routed to expert 1 first: one expert holds the whole
+    batch, far past any capacity, and each assignment is still computed;
+    also when the experts are read in place from a stack of layers."""
+    ks = jax.random.split(jax.random.PRNGKey(10), 6)
+    T, D, E, F, K, L = 48, 16, 4, 32, 2, 3
+    x = jax.random.normal(ks[0], (T, D))
+    ws = [jax.random.normal(k, (L, E) + s) * 0.1 for k, s in
+          zip(ks[1:4], ((D, F), (D, F), (F, D)))]
+    idx = jnp.stack([jnp.ones((T,), jnp.int32),
+                     jax.random.randint(ks[4], (T,), 2, E)], 1)
+    gate = jax.random.uniform(ks[5], (T, K))
+    dense = jnp.zeros((T, E)).at[jnp.arange(T)[:, None], idx].set(gate)
+    at = 2 if layer is None else layer
+    want = ref.moe_dense(x, *(w[at] for w in ws), dense)
+    if layer is None:
+        got = ops.moe_apply(x, *(w[at] for w in ws), idx, gate,
+                            dtype=jnp.float32)
+    else:
+        got = ops.moe_apply(x, *ws, idx, gate, layer=jnp.int32(layer),
+                            dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 

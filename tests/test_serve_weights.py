@@ -1,7 +1,9 @@
 """The served weight set holds matrix weights in the compute dtype.
 
-``serve_params`` rounds each matrix-product weight and the embedding table
-to bfloat16 once, where the served steps used to round them on every call.
+``serve_params`` rounds each matrix-product weight (the latent attention's
+projections, the experts and their router among them) and the embedding
+table to bfloat16 once, where the served steps used to round them on every
+call.
 The steps read those leaves only through ``cast(leaf, compute dtype)``, so
 a serve job must compute bit for bit what it computed from the float32 set
 that ``lm.init`` draws, in every family whose served step runs here.
@@ -28,7 +30,9 @@ BATCH, PROMPT, TOKENS_PER_BLOCK, BLOCKS = 3, 8, 2, 2
 FAMILIES = ["yi-6b", "mistral-nemo-12b", "yi-34b", "pixtral-12b",
             "minicpm3-4b", "deepseek-v2-lite-16b", "dbrx-132b",
             "mamba2-2.7b", "recurrentgemma-2b", "whisper-large-v3"]
-MATRIX_LEAVES = {"w", "wq", "wk", "wv", "wo", "table"}
+MATRIX_LEAVES = {"w", "wq", "wk", "wv", "wo", "table",
+                 "wdq", "wuq", "wdkv", "wkr", "wuk", "wuv",
+                 "gate_w", "up_w", "down_w", "router"}
 
 
 def _float32_set(cfg):
@@ -109,7 +113,8 @@ def test_served_set_holds_matrix_weights_in_the_compute_dtype(arch):
             assert jnp.array_equal(leaf, want), path
 
 
-@pytest.mark.parametrize("arch", ["yi-6b", "mistral-nemo-12b"])
+@pytest.mark.parametrize("arch", ["yi-6b", "mistral-nemo-12b",
+                                  "deepseek-v2-lite-16b"])
 def test_served_steps_convert_no_weight(arch):
     """The lowered prefill and decode on the served set hold no convert
     whose operand has a matrix weight's shape (per layer or stacked)."""
