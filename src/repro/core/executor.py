@@ -46,10 +46,12 @@ from .workload import Arrival, KernelSpec
 class ExecutorJob:
     """One schedulable job: ``make_block_fn(residency)`` returns a callable
     executing one block (one real jitted step) at that residency.
-    ``warmup_fn`` AOT-compiles the job's step functions without mutating its
-    state — the executor invokes it before scheduling so that measured block
-    durations (and hence the predictor's sampled ``t``) reflect steady-state
-    compute, not one-time JIT cost, as on a production system.
+    ``warmup_fn`` readies the job's compiled step functions without mutating
+    its state (a serve job runs its prefill and decode programs only for a
+    program key the process has not warmed yet) — the executor invokes it
+    before scheduling so that measured block durations (and hence the
+    predictor's sampled ``t``) reflect steady-state compute, not one-time
+    JIT cost, as on a production system.
     ``tenant`` groups jobs for the multi-tenant service's per-tenant
     metrics; it defaults to the job name."""
 

@@ -8,15 +8,16 @@ predictor exploits.
 
 Serve jobs of one configuration share what a server holds once: one weight
 set per (configuration, weight seed) and one compiled prefill/decode pair
-per (configuration, batch, prompt length, cache length).  Each job owns
-only its prompt, its KV cache and the tokens it generates.
+per (configuration, batch, prompt length, cache length), run once per
+process to warm it.  Each job owns only its prompt, its KV cache and the
+tokens it generates.
 """
 
 from __future__ import annotations
 
 import functools
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -170,6 +171,12 @@ def _decode(cfg: ArchConfig, params, token, caches, lengths):
     return jnp.argmax(logits, -1), caches
 
 
+# The program keys (configuration, batch, prompt length, cache length)
+# whose ``_prefill`` and ``_decode`` programs this process has run: a
+# serve job's warm-up runs them only for a key not in here.
+_warmed: Set[Tuple[ArchConfig, int, int, int]] = set()
+
+
 @dataclass
 class ServeJob(ExecutorJob):
     """A serving job; its blocks append each generated ``[batch]`` token
@@ -202,21 +209,29 @@ def make_serve_job(
     against a live KV cache (prefill happens in the first block).
 
     ``seed`` draws the prompt; ``weight_seed`` picks the shared weight set
-    (:func:`serve_params`).
+    (:func:`serve_params`).  The warm-up runs a prefill and a decode step
+    and discards them, so that the first block compiles nothing; it does
+    so only the first time the process meets the job's program key, and
+    returns at once for every later job of that key.
     """
     params = serve_params(cfg, weight_seed)
     max_seq = prompt_len + blocks * tokens_per_block + 8
     prompt = jax.random.randint(jax.random.PRNGKey(seed),
                                 (batch, prompt_len), 0, cfg.vocab_size)
+    program_key = (cfg, batch, prompt_len, max_seq)
     state: Dict = {"caches": None, "lengths": None, "token": None}
     generated: List = []
 
     def warmup():
-        logits, caches = _prefill(cfg, params, prompt, max_seq=max_seq)
-        tok = jnp.argmax(logits, -1)
-        lengths = jnp.full((batch,), prompt_len, jnp.int32)
-        out = _decode(cfg, params, tok, caches, lengths)
-        jax.block_until_ready(out[0])   # compile only; discard results
+        ran = program_key not in _warmed
+        if ran:
+            logits, caches = _prefill(cfg, params, prompt, max_seq=max_seq)
+            tok = jnp.argmax(logits, -1)
+            lengths = jnp.full((batch,), prompt_len, jnp.int32)
+            out = _decode(cfg, params, tok, caches, lengths)
+            jax.block_until_ready(out[0])   # compile only; discard results
+            _warmed.add(program_key)
+        tracing.count("job.warmup_ran", int(ran), job=name, max_seq=max_seq)
 
     def make_block_fn(residency: int) -> Callable[[], None]:
         def block():
